@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import compile_cache
 from repro.common.types import MLLMConfig, ModalityStub, ModelConfig
 from repro.core.engine import DFLOPEngine
 from repro.core.optimizer.space import ClusterSpec, ModuleParallelism, ParallelismPlan
@@ -150,6 +151,7 @@ def main():
         ap.error("--random bypasses the control loop (schedule_random "
                  "never reaches the controller), so --replan would only "
                  "adopt plans at exit; drop one of the two flags")
+    print(f"[cache] {compile_cache.enable()}")
 
     enc_cfg, llm_cfg, mcfg = tiny_configs() if args.tiny else (ENC, LLM, MCFG)
     if args.shift_at:

@@ -22,7 +22,6 @@ from functools import partial
 from typing import Callable
 
 import jax
-from repro.common import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -59,7 +58,7 @@ def explicit_gather_scatter(mesh: Mesh, axis: str):
             shard = full.shape[0] // n
             return jax.lax.dynamic_slice_in_dim(full, idx * shard, shard, 0)
 
-        return compat.shard_map(inner, mesh=mesh, in_specs=P(axis),
+        return jax.shard_map(inner, mesh=mesh, in_specs=P(axis),
                              out_specs=P(axis))(x)
 
     return fn

@@ -19,7 +19,6 @@ from functools import partial
 from typing import Callable
 
 import jax
-from repro.common import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -81,7 +80,7 @@ def pipeline_forward(mesh: Mesh, stage_fn: Callable, axis: str = "stage"):
 
     in_specs = (P(axis), P())
     out_specs = P()
-    return compat.shard_map(inner, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 

@@ -31,11 +31,31 @@ transposes drop the tail cotangents automatically.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 # Reserved segment id for padded positions: real segment ids are ≥ 0
 # (0 = packing tail, 1..n = instances), so −1 never matches under the
 # ``seg_q == seg_k`` mask.
 PAD_SEGMENT = -1
+
+# Mosaic loads and stores VMEM in (8, 128) tiles and cannot prove a
+# per-timestep dynamic row index aligned, so the scans' time loops move
+# ROWS timesteps per iteration: one aligned (ROWS, ·) tile load or store
+# per stream, with the ROWS steps unrolled over the tile's rows.
+ROWS = 8
+
+
+def round_up(n: int, m: int) -> int:
+    """
+    >>> round_up(67, 8)
+    72
+    """
+    return -(-int(n) // m) * m
+
+
+def row_tile(i):
+    """Aligned ``ROWS``-row slice for time-loop iteration ``i``."""
+    return pl.ds(pl.multiple_of(i * ROWS, ROWS), ROWS)
 
 
 def pick_block(s: int, target: int) -> tuple:

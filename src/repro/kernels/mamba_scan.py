@@ -6,7 +6,8 @@
 TPU adaptation: time is chunked; channels are blocked so each program
 instance owns a (c_blk, N) state tile in VMEM scratch carried across chunk
 iterations.  Grid (B, n_cblk, n_chunks), chunk axis innermost/sequential.
-B_t/C_t (shared across channels) are re-read per channel block — they are
+Inside a chunk a fori_loop moves aligned 8-row tiles of every stream and
+unrolls the 8 timesteps of each (``blocking.row_tile``).  B_t/C_t (shared across channels) are re-read per channel block — they are
 (chunk, N) tiles, tiny next to the (chunk, c_blk) channel streams.
 
 Backward (``docs/kernels.md``): the forward additionally emits each chunk's
@@ -35,7 +36,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocking import pad_axis, pick_block
+from repro.kernels.blocking import ROWS, pad_axis, pick_block, round_up, row_tile
+
+# channel block of the backward: its (chunk, c_blk, N) f32 replay history
+# pads N to 128 lanes in VMEM, 8 MiB at chunk 128 (the scoped limit is 16)
+BWD_C_BLK = 128
+
+
+def _load(ref, rows):
+    return ref[0, rows].astype(jnp.float32)            # (ROWS, ·)
 
 
 def _fwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, hinit_ref,
@@ -49,20 +58,22 @@ def _fwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, hinit_ref,
     hinit_ref[0, 0] = h_scr[...]                       # this chunk's h_{-1}
 
     A = a_ref[...].astype(jnp.float32)                 # (c_blk, N)
-    D = d_ref[...].astype(jnp.float32)                 # (c_blk,)
+    D = d_ref[0].astype(jnp.float32)                   # (c_blk,)
 
-    def step(t, h):
-        u_t = u_ref[0, t].astype(jnp.float32)          # (c_blk,)
-        dt_t = dt_ref[0, t].astype(jnp.float32)        # (c_blk,)
-        b_t = b_ref[0, t].astype(jnp.float32)          # (N,)
-        c_t = c_ref[0, t].astype(jnp.float32)          # (N,)
-        decay = jnp.exp(dt_t[:, None] * A)             # (c_blk, N)
-        h = h * decay + (dt_t * u_t)[:, None] * b_t[None, :]
-        y = jnp.sum(h * c_t[None, :], axis=1) + D * u_t
-        y_ref[0, t] = y.astype(y_ref.dtype)
+    def tile_step(i, h):
+        rows = row_tile(i)
+        u8, dt8 = _load(u_ref, rows), _load(dt_ref, rows)
+        b8, c8 = _load(b_ref, rows), _load(c_ref, rows)
+        ys = []
+        for j in range(ROWS):
+            u_t, dt_t = u8[j], dt8[j]                  # (c_blk,)
+            decay = jnp.exp(dt_t[:, None] * A)         # (c_blk, N)
+            h = h * decay + (dt_t * u_t)[:, None] * b8[j:j + 1]
+            ys.append(jnp.sum(h * c8[j:j + 1], axis=1) + D * u_t)
+        y_ref[0, rows] = jnp.stack(ys).astype(y_ref.dtype)
         return h
 
-    h_scr[...] = jax.lax.fori_loop(0, chunk, step, h_scr[...])
+    h_scr[...] = jax.lax.fori_loop(0, chunk // ROWS, tile_step, h_scr[...])
 
 
 def _bwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, hinit_ref, dy_ref,
@@ -79,57 +90,67 @@ def _bwd_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, hinit_ref, dy_ref,
         g_scr[...] = jnp.zeros_like(g_scr)
 
     A = a_ref[...].astype(jnp.float32)                 # (c_blk, N)
-    D = d_ref[...].astype(jnp.float32)                 # (c_blk,)
+    D = d_ref[0].astype(jnp.float32)                   # (c_blk,)
 
-    def replay(t, h):
-        hist_scr[t] = h
-        dt_t = dt_ref[0, t].astype(jnp.float32)
-        u_t = u_ref[0, t].astype(jnp.float32)
-        b_t = b_ref[0, t].astype(jnp.float32)
-        decay = jnp.exp(dt_t[:, None] * A)
-        return h * decay + (dt_t * u_t)[:, None] * b_t[None, :]
+    def replay(i, h):
+        rows = row_tile(i)
+        u8, dt8, b8 = _load(u_ref, rows), _load(dt_ref, rows), _load(b_ref, rows)
+        for j in range(ROWS):
+            hist_scr[i * ROWS + j] = h
+            dt_t = dt8[j]
+            decay = jnp.exp(dt_t[:, None] * A)
+            h = h * decay + (dt_t * u8[j])[:, None] * b8[j:j + 1]
+        return h
 
-    jax.lax.fori_loop(0, chunk, replay, hinit_ref[0, 0].astype(jnp.float32))
+    jax.lax.fori_loop(0, chunk // ROWS, replay,
+                      hinit_ref[0, 0].astype(jnp.float32))
 
     def bstep(s, carry):
         g, da_acc, dd_acc = carry
-        t = chunk - 1 - s
-        u_t = u_ref[0, t].astype(jnp.float32)
-        dt_t = dt_ref[0, t].astype(jnp.float32)
-        b_t = b_ref[0, t].astype(jnp.float32)
-        c_t = c_ref[0, t].astype(jnp.float32)
-        dy_t = dy_ref[0, t].astype(jnp.float32)        # (c_blk,)
-        h_prev = hist_scr[t]                           # (c_blk, N)
-        decay = jnp.exp(dt_t[:, None] * A)
-        x_t = dt_t * u_t
-        h_t = h_prev * decay + x_t[:, None] * b_t[None, :]
+        i = chunk // ROWS - 1 - s
+        rows = row_tile(i)
+        u8, dt8 = _load(u_ref, rows), _load(dt_ref, rows)
+        b8, c8 = _load(b_ref, rows), _load(c_ref, rows)
+        dy8 = _load(dy_ref, rows)
+        du, ddt, db, dc = ([None] * ROWS for _ in range(4))
+        for j in reversed(range(ROWS)):
+            u_t, dt_t, dy_t = u8[j], dt8[j], dy8[j]    # (c_blk,)
+            b_t, c_t = b8[j:j + 1], c8[j:j + 1]        # (1, N)
+            h_prev = hist_scr[i * ROWS + j]            # (c_blk, N)
+            decay = jnp.exp(dt_t[:, None] * A)
+            x_t = dt_t * u_t
+            h_t = h_prev * decay + x_t[:, None] * b_t
 
-        gt = g + dy_t[:, None] * c_t[None, :]          # full dL/dh_t
-        dc_ref[0, 0, t] = jnp.sum(dy_t[:, None] * h_t, axis=0)
-        db_ref[0, 0, t] = jnp.sum(gt * x_t[:, None], axis=0)
-        gh = gt * h_prev * decay                       # d(decay) chain
-        dx = jnp.sum(gt * b_t[None, :], axis=1)
-        ddt_ref[0, t] = dx * u_t + jnp.sum(gh * A, axis=1)
-        du_ref[0, t] = dx * dt_t + D * dy_t
-        da_acc = da_acc + gh * dt_t[:, None]
-        dd_acc = dd_acc + dy_t * u_t
-        return gt * decay, da_acc, dd_acc
+            gt = g + dy_t[:, None] * c_t               # full dL/dh_t
+            dc[j] = jnp.sum(dy_t[:, None] * h_t, axis=0)
+            db[j] = jnp.sum(gt * x_t[:, None], axis=0)
+            gh = gt * h_prev * decay                   # d(decay) chain
+            dx = jnp.sum(gt * b_t, axis=1)
+            ddt[j] = dx * u_t + jnp.sum(gh * A, axis=1)
+            du[j] = dx * dt_t + D * dy_t
+            da_acc = da_acc + gh * dt_t[:, None]
+            dd_acc = dd_acc + dy_t * u_t
+            g = gt * decay
+        du_ref[0, rows] = jnp.stack(du)
+        ddt_ref[0, rows] = jnp.stack(ddt)
+        db_ref[0, 0, rows] = jnp.stack(db)
+        dc_ref[0, 0, rows] = jnp.stack(dc)
+        return g, da_acc, dd_acc
 
     g, da_acc, dd_acc = jax.lax.fori_loop(
-        0, chunk, bstep,
-        (g_scr[...], jnp.zeros_like(g_scr), jnp.zeros_like(d_ref,
-                                                           dtype=jnp.float32)))
+        0, chunk // ROWS, bstep,
+        (g_scr[...], jnp.zeros_like(g_scr), jnp.zeros_like(D)))
     g_scr[...] = g
 
     @pl.when(ic == 0)
     def _first():
         da_ref[0] = da_acc
-        dd_ref[0] = dd_acc
+        dd_ref[0, 0] = dd_acc
 
     @pl.when(ic > 0)
     def _rest():
         da_ref[0] += da_acc
-        dd_ref[0] += dd_acc
+        dd_ref[0, 0] += dd_acc
 
 
 def _fwd_call(u, dt, B_t, C_t, A, D, c, cb, interpret):
@@ -145,7 +166,7 @@ def _fwd_call(u, dt, B_t, C_t, A, D, c, cb, interpret):
             pl.BlockSpec((1, c, N), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, c, N), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((cb, N), lambda b, j, i: (j, 0)),
-            pl.BlockSpec((cb,), lambda b, j, i: (j,)),
+            pl.BlockSpec((1, cb), lambda b, j, i: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, c, cb), lambda b, j, i: (b, i, j)),
@@ -157,7 +178,7 @@ def _fwd_call(u, dt, B_t, C_t, A, D, c, cb, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((cb, N), jnp.float32)],
         interpret=interpret,
-    )(u, dt, B_t, C_t, A, D)
+    )(u, dt, B_t, C_t, A, D.reshape(1, di))
 
 
 def _bwd_call(u, dt, B_t, C_t, A, D, h_init, dy, c, cb, interpret):
@@ -166,7 +187,7 @@ def _bwd_call(u, dt, B_t, C_t, A, D, h_init, dy, c, cb, interpret):
     n_chunks, n_cblk = S // c, di // cb
     rev = n_chunks - 1                                 # reversed chunk walk
     f32 = jnp.float32
-    return pl.pallas_call(
+    du, ddt, dB_p, dC_p, dA_p, dD_p = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=c),
         grid=(B, n_cblk, n_chunks),
         in_specs=[
@@ -175,7 +196,7 @@ def _bwd_call(u, dt, B_t, C_t, A, D, h_init, dy, c, cb, interpret):
             pl.BlockSpec((1, c, N), lambda b, j, i: (b, rev - i, 0)),
             pl.BlockSpec((1, c, N), lambda b, j, i: (b, rev - i, 0)),
             pl.BlockSpec((cb, N), lambda b, j, i: (j, 0)),
-            pl.BlockSpec((cb,), lambda b, j, i: (j,)),
+            pl.BlockSpec((1, cb), lambda b, j, i: (0, j)),
             pl.BlockSpec((1, 1, cb, N), lambda b, j, i: (b, rev - i, j, 0)),
             pl.BlockSpec((1, c, cb), lambda b, j, i: (b, rev - i, j)),
         ],
@@ -185,7 +206,7 @@ def _bwd_call(u, dt, B_t, C_t, A, D, h_init, dy, c, cb, interpret):
             pl.BlockSpec((1, 1, c, N), lambda b, j, i: (j, b, rev - i, 0)),
             pl.BlockSpec((1, 1, c, N), lambda b, j, i: (j, b, rev - i, 0)),
             pl.BlockSpec((1, cb, N), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, cb), lambda b, j, i: (b, j)),
+            pl.BlockSpec((1, 1, cb), lambda b, j, i: (b, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, di), f32),          # du
@@ -193,12 +214,13 @@ def _bwd_call(u, dt, B_t, C_t, A, D, h_init, dy, c, cb, interpret):
             jax.ShapeDtypeStruct((n_cblk, B, S, N), f32),   # dB partial
             jax.ShapeDtypeStruct((n_cblk, B, S, N), f32),   # dC partial
             jax.ShapeDtypeStruct((B, di, N), f32),          # dA partial
-            jax.ShapeDtypeStruct((B, di), f32),             # dD partial
+            jax.ShapeDtypeStruct((B, 1, di), f32),          # dD partial
         ],
         scratch_shapes=[pltpu.VMEM((cb, N), jnp.float32),
                         pltpu.VMEM((c, cb, N), jnp.float32)],
         interpret=interpret,
-    )(u, dt, B_t, C_t, A, D, h_init, dy)
+    )(u, dt, B_t, C_t, A, D.reshape(1, di), h_init, dy)
+    return du, ddt, dB_p, dC_p, dA_p, dD_p[:, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -214,8 +236,9 @@ def _scan_fwd_rule(u, dt, B_t, C_t, A, D, c, cb, interpret):
 
 def _scan_bwd_rule(c, cb, interpret, res, dy):
     u, dt, B_t, C_t, A, D, h_init = res
+    cb_bwd = BWD_C_BLK if cb % BWD_C_BLK == 0 else cb
     du, ddt, dB_p, dC_p, dA_p, dD_p = _bwd_call(
-        u, dt, B_t, C_t, A, D, h_init, dy, c, cb, interpret)
+        u, dt, B_t, C_t, A, D, h_init, dy, c, cb_bwd, interpret)
     return (du.astype(u.dtype), ddt.astype(dt.dtype),
             jnp.sum(dB_p, axis=0).astype(B_t.dtype),
             jnp.sum(dC_p, axis=0).astype(C_t.dtype),
@@ -232,7 +255,8 @@ def mamba_scan_bsd(u, dt, B_t, C_t, A, D, *, chunk: int = 128,
     """u, dt: (B, S, di); B_t, C_t: (B, S, N); A: (di, N); D: (di,).
     Returns y: (B, S, di).  Differentiable in every array input."""
     B, S, di = u.shape
-    c, S_p = pick_block(S, chunk)
+    # the time loop moves whole ROWS-step tiles: round both up to ROWS
+    c, S_p = pick_block(round_up(S, ROWS), round_up(chunk, ROWS))
     cb, di_p = pick_block(di, c_blk)
     # dt = 0 on the pad makes every padded step an identity; padded
     # channels (A = D = 0) contribute nothing and are sliced off.

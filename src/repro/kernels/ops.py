@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute with ``interpret=True`` — the kernel
-body runs op-by-op in Python, validating the exact TPU program against the
-``ref.py`` oracles.  On a real TPU backend ``interpret=False`` compiles the
-Mosaic kernel.
+On a TPU backend ``interpret=False`` compiles the Mosaic kernel.  On CPU the
+kernels execute with ``interpret=True`` — the kernel body runs op-by-op in
+Python, validating the exact TPU program against the ``ref.py`` oracles.
+Any other backend can do neither and is refused.
 """
 from __future__ import annotations
 
@@ -16,7 +16,11 @@ from repro.kernels.rwkv6_scan import rwkv6_scan_bhsm
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"the Pallas TPU kernels cannot run on a {backend!r} backend")
+    return backend == "cpu"
 
 
 def packed_flash_attention(q, k, v, *, segment_ids=None, causal=True,

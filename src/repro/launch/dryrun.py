@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 Lowers + compiles the production step function for every
@@ -18,6 +15,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Optional
@@ -538,6 +536,9 @@ def _dump(rec: dict, out_dir: Optional[str]) -> dict:
 
 
 def main():
+    # 512 placeholder host devices; must precede JAX's backend start-up,
+    # which the first device query below triggers
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(INPUT_SHAPES))

@@ -111,17 +111,17 @@ def fleet_plan_mesh(plan: ParallelismPlan, devices: Sequence):
         raise ValueError("fleet mesh over an empty roster")
     # local import: reshard imports space/executor, not the other way round
     from repro.launch.reshard import PLAN_AXES
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
     mp = plan.llm
     if mp.chips <= n:
-        return compat_make_mesh((mp.dp, mp.pp, mp.tp), PLAN_AXES,
-                                devices=devices[:mp.chips])
+        return make_mesh((mp.dp, mp.pp, mp.tp), PLAN_AXES,
+                         devices=devices[:mp.chips])
     tp = largest_divisor_leq(mp.tp, n)
     pp = largest_divisor_leq(mp.pp, max(n // tp, 1))
     dp = largest_divisor_leq(mp.dp, max(n // (tp * pp), 1))
-    return compat_make_mesh((dp, pp, tp), PLAN_AXES,
-                            devices=devices[:dp * pp * tp])
+    return make_mesh((dp, pp, tp), PLAN_AXES,
+                     devices=devices[:dp * pp * tp])
 
 
 class FleetManager:

@@ -1,25 +1,23 @@
 """Production meshes (TPU v5e target).
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state.  The dry-run sets XLA_FLAGS for 512 host devices *before* any jax
-import; tests and benchmarks see the default single device.
+state.  The dry-run's ``main()`` sets XLA_FLAGS for 512 host devices before
+JAX initialises its backends; tests and benchmarks see the default device
+count.
 """
 from __future__ import annotations
 
 import jax
 
 
-def compat_make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: `axis_types` (and
-    `jax.sharding.AxisType`) only exist in newer releases; older ones
-    default to Auto axes anyway.  `devices` restricts the mesh to a subset
-    of the local devices (a re-planned θ* rarely uses all of them)."""
+def make_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with explicit Auto axes.  `devices` restricts the
+    mesh to a subset of the local devices (a re-planned θ* rarely uses all
+    of them)."""
     kw = {} if devices is None else {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-                             **kw)
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def host_groups(devices, per_host: int):
@@ -57,12 +55,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over forced host devices (tests / examples)."""
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh) -> tuple:

@@ -8,7 +8,7 @@ swapped θ* is a fiction.  Three pieces:
 
   * ``plan_mesh(plan)`` — the ``(data, stage, model)`` mesh a
     `ParallelismPlan`'s LLM parallelism implies, built via
-    `launch.mesh.compat_make_mesh` over a prefix of the local devices.
+    `launch.mesh.make_mesh` over a prefix of the local devices.
   * ``reshard_params(params, old_plan, new_plan)`` — re-stack
     stage-stacked leaves for the new PP degree (generalized
     `executor.stack_stage_params`), then `jax.device_put` onto the new
@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.optimizer.space import ParallelismPlan
 from repro.core.pipeline.executor import stack_stage_params
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 # Axis convention for plan-implied meshes.  `pipeline_forward` shards
 # stage-stacked leaves over "stage"; "data"/"model" replicate them.
@@ -76,8 +76,8 @@ def plan_mesh(plan: ParallelismPlan, *, devices=None) -> Mesh:
     if n > len(devices):
         raise ValueError(
             f"plan {plan.as_tuple()} needs {n} devices, have {len(devices)}")
-    return compat_make_mesh((mp.dp, mp.pp, mp.tp), PLAN_AXES,
-                            devices=devices[:n])
+    return make_mesh((mp.dp, mp.pp, mp.tp), PLAN_AXES,
+                     devices=devices[:n])
 
 
 def clamped_plan_mesh(plan: ParallelismPlan, *, devices=None) -> Mesh:
@@ -92,8 +92,8 @@ def clamped_plan_mesh(plan: ParallelismPlan, *, devices=None) -> Mesh:
     tp = min(plan.llm.tp, n)
     pp = min(plan.llm.pp, max(n // tp, 1))
     dp = min(plan.llm.dp, max(n // (tp * pp), 1))
-    return compat_make_mesh((dp, pp, tp), PLAN_AXES,
-                            devices=devices[:dp * pp * tp])
+    return make_mesh((dp, pp, tp), PLAN_AXES,
+                     devices=devices[:dp * pp * tp])
 
 
 def param_bytes(params) -> int:
@@ -129,11 +129,6 @@ def _restackable(params, old_pp: int, new_pp: int) -> bool:
     return all((leaf.shape[0] * leaf.shape[1]) % new_pp == 0
                for leaf in jax.tree_util.tree_leaves(params)) \
         if _stage_stacked(params, old_pp) else False
-
-
-def _supports_donate() -> bool:
-    import inspect
-    return "donate" in inspect.signature(jax.device_put).parameters
 
 
 def _any_deleted(params) -> bool:
@@ -205,11 +200,8 @@ def reshard_params(params, old_plan: ParallelismPlan,
                              and getattr(leaf, "sharding", None) == sharding)))
 
     target = jax.tree_util.tree_map(lambda _: sharding, params)
-    if donate and _supports_donate():
-        new_params = jax.device_put(params, target, donate=True)
-    else:
-        new_params = jax.device_put(params, target)
-    new_params = jax.block_until_ready(new_params)
+    new_params = jax.block_until_ready(
+        jax.device_put(params, target, donate=donate))
 
     report = ReshardReport(
         old_plan=old_plan.as_tuple(), new_plan=new_plan.as_tuple(),

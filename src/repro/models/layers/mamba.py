@@ -12,7 +12,6 @@ from __future__ import annotations
 import math
 
 import jax
-from repro.common import compat
 import jax.numpy as jnp
 
 from repro.common.types import ModelConfig
@@ -167,7 +166,7 @@ def ssm_scan_sharded(u, dt, B_t, C_t, A, D, shard_ctx, chunked=False):
                            (u.shape[0], u.shape[2], A.shape[1]), mesh)
 
     inner = (ssm_scan_chunked if chunked else ssm_scan_xla)
-    fn = compat.shard_map(inner, mesh=mesh,
+    fn = jax.shard_map(inner, mesh=mesh,
                        in_specs=(spec_u, spec_u, spec_bc, spec_bc,
                                  spec_A, spec_D),
                        out_specs=(spec_u, spec_h), check_vma=False)

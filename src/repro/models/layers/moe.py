@@ -19,7 +19,6 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
-from repro.common import compat
 import jax.numpy as jnp
 
 from repro.common.types import ModelConfig
@@ -251,7 +250,7 @@ def apply_ep_shard_map(params, x, cfg: ModelConfig, shard_ctx, *,
             lb = jax.lax.pmean(lb, a)
         return y.reshape(Bl, Sl, d), lb
 
-    sm = compat.shard_map(local, mesh=mesh,
+    sm = jax.shard_map(local, mesh=mesh,
                        in_specs=({k: in_specs[k] for k in params}, x_spec),
                        out_specs=(x_spec, P()), check_vma=False)
     # lb is computed identically on every shard (replicated routing)
@@ -316,7 +315,7 @@ def _apply_tp_shard_map(params, x, cfg: ModelConfig, shard_ctx, *,
             lb = jax.lax.pmean(lb, a)
         return y.reshape(Bl, Sl, d), lb
 
-    sm = compat.shard_map(local, mesh=mesh,
+    sm = jax.shard_map(local, mesh=mesh,
                        in_specs=({k: in_specs[k] for k in params}, x_spec),
                        out_specs=(x_spec, P()), check_vma=False)
     return sm(params, x)

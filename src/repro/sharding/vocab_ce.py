@@ -20,7 +20,6 @@ from functools import partial
 from typing import Callable, Optional, Tuple
 
 import jax
-from repro.common import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -86,7 +85,7 @@ def make_vocab_parallel_ce(mesh: Mesh, batch_axes: Tuple[str, ...],
         # model-axis contributions are already identical (post-psum)
         return loss_sum / jnp.maximum(count, 1.0)
 
-    sm = compat.shard_map(local_fn, mesh=mesh,
+    sm = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(w_spec, h_spec, l_spec),
                        out_specs=P(), check_vma=False)
 

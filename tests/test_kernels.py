@@ -350,3 +350,15 @@ def test_model_grad_through_pallas_impls():
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             rtol=2e-3, atol=2e-3, err_msg=jax.tree_util.keystr(path))
+
+
+def test_kernels_refuse_backends_they_cannot_run_on(monkeypatch):
+    """Mosaic on a TPU, the interpreter on the CPU, nothing elsewhere: a
+    GPU backend must not silently fall back to interpret mode."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops._interpret()

@@ -28,8 +28,8 @@ def test_vocab_parallel_ce_matches_dense():
         import jax, jax.numpy as jnp, numpy as np
         from repro.sharding.vocab_ce import make_vocab_parallel_ce
         from repro.train.loss import cross_entropy
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2,4), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,4), ("data","model"))
         B,S,D,V = 4, 16, 32, 64
         h = jax.random.normal(jax.random.PRNGKey(0), (B,S,D))
         w = jax.random.normal(jax.random.PRNGKey(1), (D,V)) * 0.1
@@ -55,8 +55,8 @@ def test_inter_model_communicator_preserves_values():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.communicator import make_communicator
         from repro.sharding.partition import AxisAssignment
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2,4), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,4), ("data","model"))
         enc = AxisAssignment(batch=("data","model"), tensor=())
         llm = AxisAssignment(batch=("data",), tensor=("model",))
         comm = make_communicator(mesh, enc, llm)
@@ -88,8 +88,8 @@ def test_pipeline_executor_matches_sequential():
         from repro.core.pipeline.executor import (build_stage_fn,
                                                   pipeline_forward,
                                                   stack_stage_params)
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4,), ("stage",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("stage",))
         n_layers, d = 8, 16
         key = jax.random.PRNGKey(0)
         W = jax.random.normal(key, (n_layers, d, d)) * (d ** -0.5)
@@ -224,8 +224,8 @@ def test_dryrun_smoke_small_mesh():
         from repro.configs import get_config
         from repro.common.types import INPUT_SHAPES, ShapeSpec
         from repro.launch import dryrun as D
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         spec = get_config("gemma-2b")
         spec = dataclasses.replace(spec, desc=spec.reduced_desc())
         shape = ShapeSpec("mini", 256, 16, "train")
@@ -244,8 +244,8 @@ def test_ep_shard_map_moe_matches_dense():
         import jax, jax.numpy as jnp, numpy as np
         from repro.common.types import ModelConfig
         from repro.models.layers import moe
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2,4), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,4), ("data","model"))
         cfg = ModelConfig(name="m", family="moe", n_layers=2, d_model=64,
                           n_heads=4, n_kv_heads=4, d_ff=128, vocab_size=97,
                           ffn_pattern=("moe",), n_experts=8, top_k=2,
@@ -273,8 +273,8 @@ def test_sharded_mamba_scan_matches_plain():
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.layers.mamba import ssm_scan_xla, ssm_scan_sharded
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2,4), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,4), ("data","model"))
         B,S,di,N = 4, 32, 16, 8
         ks = jax.random.split(jax.random.PRNGKey(0), 6)
         u = jax.random.normal(ks[0], (B,S,di))
@@ -308,8 +308,8 @@ def test_tp_expert_shard_map_moe_non_divisible():
         import jax, jax.numpy as jnp, numpy as np
         from repro.common.types import ModelConfig
         from repro.models.layers import moe
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2,4), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,4), ("data","model"))
         cfg = ModelConfig(name="m", family="moe", n_layers=2, d_model=64,
                           n_heads=4, n_kv_heads=4, d_ff=128, vocab_size=97,
                           ffn_pattern=("moe",), n_experts=6, top_k=2,
