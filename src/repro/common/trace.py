@@ -11,6 +11,16 @@ Event kinds map onto trace phases:
   span()/complete() -> "X" (complete slice: ts + dur)
   instant()         -> "i" (e.g. plan hot-swap markers)
   counter()         -> "C" (rolling metrics: imbalance, bubble fraction)
+
+Two destinations.  The buffer above keeps its own clock
+(``time.monotonic`` from the recorder's creation) and reaches only the
+Chrome export.  ``span()`` also opens a ``jax.profiler.TraceAnnotation``
+named ``dflop.<cat>.<name>`` around the block, whether or not the buffer
+is enabled: while a profiler session runs (``jax.profiler.start_trace``)
+the span lands in its ``.xplane.pb`` on the host thread that ran it, on
+the same clock as the device's ops; with no session it costs a few
+hundred nanoseconds and records nothing.  ``complete()`` slices (timed
+after the fact), instants and counters reach only the Chrome export.
 """
 from __future__ import annotations
 
@@ -20,6 +30,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _PID = 1
 
@@ -57,16 +69,18 @@ class TraceRecorder:
     # ------------------------------------------------------------------ #
     @contextmanager
     def span(self, name: str, *, cat: str = "runtime", tid: int = 0, **args):
-        """Time a block as a complete slice.  ~1 µs overhead when enabled."""
-        if not self.enabled:
-            yield self
-            return
-        ts = self.now_us()
-        try:
-            yield self
-        finally:
-            self._push(("X", name, cat, ts, self.now_us() - ts, tid,
-                        args or None))
+        """Time a block as a complete slice (~1 µs when enabled) and mark it
+        ``dflop.<cat>.<name>`` in any running profiler session."""
+        with TraceAnnotation(f"dflop.{cat}.{name}"):
+            if not self.enabled:
+                yield self
+                return
+            ts = self.now_us()
+            try:
+                yield self
+            finally:
+                self._push(("X", name, cat, ts, self.now_us() - ts, tid,
+                            args or None))
 
     def complete(self, name: str, ts_us: float, dur_us: float, *,
                  cat: str = "runtime", tid: int = 0,

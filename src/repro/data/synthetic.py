@@ -16,7 +16,11 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.common.trace import TraceRecorder
 from repro.data.items import DataItem
+
+# records nothing itself: its spans reach only a running profiler session
+_SPANS = TraceRecorder(enabled=False)
 
 
 @dataclass(frozen=True)
@@ -83,28 +87,29 @@ class MixedDataset:
                     vocab_size: int, max_media: int, max_text: int,
                     seed: int = 0) -> dict:
         """Tensorize items into a padded multimodal batch (stub frontend)."""
-        rng = np.random.default_rng(seed)
-        B = len(items)
-        t_media = max_media
-        media = np.zeros((B, t_media, embed_dim), np.float32)
-        media_mask = np.zeros((B, t_media), np.int32)
-        text = np.zeros((B, max_text), np.int32)
-        text_mask = np.zeros((B, max_text), np.int32)
-        labels = np.full((B, max_text), -1, np.int32)
-        tpm = self.tokens_per_media_item
-        for i, it in enumerate(items):
-            m = min(it.n_media_items * tpm, t_media)
-            media[i, :m] = rng.standard_normal((m, embed_dim)) * 0.02
-            media_mask[i, :m] = 1
-            t = min(it.text_len, max_text)
-            toks = rng.integers(1, vocab_size, size=t)
-            text[i, :t] = toks
-            text_mask[i, :t] = 1
-            labels[i, : t - 1] = toks[1:]
-        return {
-            "media_embeds": media,
-            "media_mask": media_mask,
-            "text_tokens": text,
-            "text_mask": text_mask,
-            "labels": labels,
-        }
+        with _SPANS.span("materialize", cat="data"):
+            rng = np.random.default_rng(seed)
+            B = len(items)
+            t_media = max_media
+            media = np.zeros((B, t_media, embed_dim), np.float32)
+            media_mask = np.zeros((B, t_media), np.int32)
+            text = np.zeros((B, max_text), np.int32)
+            text_mask = np.zeros((B, max_text), np.int32)
+            labels = np.full((B, max_text), -1, np.int32)
+            tpm = self.tokens_per_media_item
+            for i, it in enumerate(items):
+                m = min(it.n_media_items * tpm, t_media)
+                media[i, :m] = rng.standard_normal((m, embed_dim)) * 0.02
+                media_mask[i, :m] = 1
+                t = min(it.text_len, max_text)
+                toks = rng.integers(1, vocab_size, size=t)
+                text[i, :t] = toks
+                text_mask[i, :t] = 1
+                labels[i, : t - 1] = toks[1:]
+            return {
+                "media_embeds": media,
+                "media_mask": media_mask,
+                "text_tokens": text,
+                "text_mask": text_mask,
+                "labels": labels,
+            }

@@ -376,27 +376,29 @@ def apply(params, x, cfg: ModelConfig, *, positions=None, segment_ids=None,
             q = apply_rope(q, pos[:, None], cfg.rope_theta)
             k = apply_rope(k, pos[:, None], cfg.rope_theta)
         cache = cache_write(cache, k, v, pos)
-        out = attend_cache(q, cache["k"], cache["v"], cache["kpos"], pos,
-                           window=window)
+        with jax.named_scope("dflop.attention"):
+            out = attend_cache(q, cache["k"], cache["v"], cache["kpos"], pos,
+                               window=window)
     else:
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         if cfg.use_rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
-        if impl == "naive":
-            out = attend_naive(q, k, v, causal=cfg.causal, window=window,
-                               seg_q=segment_ids, seg_k=segment_ids)
-        elif impl == "pallas":
-            from repro.kernels import ops as kops
-            out = kops.packed_flash_attention(
-                q, k, v, segment_ids=segment_ids, causal=cfg.causal,
-                window=window)
-        else:
-            out = flash_attention_xla(q, k, v, causal=cfg.causal,
-                                      window=window, seg_q=segment_ids,
-                                      seg_k=segment_ids,
-                                      block_q=block, block_k=block)
+        with jax.named_scope("dflop.attention"):
+            if impl == "naive":
+                out = attend_naive(q, k, v, causal=cfg.causal, window=window,
+                                   seg_q=segment_ids, seg_k=segment_ids)
+            elif impl == "pallas":
+                from repro.kernels import ops as kops
+                out = kops.packed_flash_attention(
+                    q, k, v, segment_ids=segment_ids, causal=cfg.causal,
+                    window=window)
+            else:
+                out = flash_attention_xla(q, k, v, causal=cfg.causal,
+                                          window=window, seg_q=segment_ids,
+                                          seg_k=segment_ids,
+                                          block_q=block, block_k=block)
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return y, cache

@@ -64,20 +64,23 @@ def encode_media(params, mcfg: MLLMConfig, media_embeds, media_mask=None,
         # mask -> segment ids: padding gets segment 0, real tokens segment 1.
         # (multi-image packing can supply richer ids via media_mask directly.)
         seg = media_mask.astype(jnp.int32)
-    h, _, _ = model_lib.forward(params["encoder"], mcfg.encoder,
-                                embeds=media_embeds, segment_ids=seg, ctx=ctx)
-    if communicator is not None:
-        # Inter-model Communicator: reshard encoder output from the encoder's
-        # data-parallel layout to the LLM's (paper Fig. 6).
-        h = communicator(h)
-    h = apply_connector(params["connector"], h, mcfg)
-    if mcfg.tokens_per_item_out:
-        t_in = h.shape[1]
-        factor = max(1, t_in // mcfg.tokens_per_item_out)
-        if factor > 1:
-            b, _, d = h.shape
-            h = h[:, : (t_in // factor) * factor]
-            h = h.reshape(b, t_in // factor, factor, d).mean(axis=2)
+    with jax.named_scope("dflop.encoder"):
+        h, _, _ = model_lib.forward(params["encoder"], mcfg.encoder,
+                                    embeds=media_embeds, segment_ids=seg,
+                                    ctx=ctx)
+    with jax.named_scope("dflop.connector"):
+        if communicator is not None:
+            # Inter-model Communicator: reshard encoder output from the
+            # encoder's data-parallel layout to the LLM's (paper Fig. 6).
+            h = communicator(h)
+        h = apply_connector(params["connector"], h, mcfg)
+        if mcfg.tokens_per_item_out:
+            t_in = h.shape[1]
+            factor = max(1, t_in // mcfg.tokens_per_item_out)
+            if factor > 1:
+                b, _, d = h.shape
+                h = h[:, : (t_in // factor) * factor]
+                h = h.reshape(b, t_in // factor, factor, d).mean(axis=2)
     return h
 
 
@@ -91,20 +94,22 @@ def forward_train(params, mcfg: MLLMConfig, batch, ctx: Optional[FwdCtx] = None,
     media = encode_media(params, mcfg, batch["media_embeds"],
                          batch.get("media_mask"), ctx=enc_ctx or ctx,
                          communicator=communicator)
-    llm_cfg = mcfg.llm
-    compute_dtype = jnp.dtype(llm_cfg.dtype)
-    text_emb = embed_lib.encode(params["llm"]["embed"],
-                                batch["text_tokens"], compute_dtype)
-    x = jnp.concatenate([media.astype(compute_dtype), text_emb], axis=1)
-    B, T_m = media.shape[0], media.shape[1]
-    T_t = text_emb.shape[1]
-    positions = jnp.broadcast_to(jnp.arange(T_m + T_t)[None], (B, T_m + T_t))
-    seg = None
-    if "media_mask" in batch and "text_mask" in batch:
-        m_seg = jnp.ones((B, T_m), jnp.int32)
-        t_seg = jnp.where(batch["text_mask"] > 0, 1, 0).astype(jnp.int32)
-        seg = jnp.concatenate([m_seg, t_seg], axis=1)
-    logits, _, aux = model_lib.forward(params["llm"], llm_cfg, embeds=x,
-                                       positions=positions, segment_ids=seg,
-                                       ctx=ctx)
-    return logits[:, T_m:], aux
+    with jax.named_scope("dflop.llm"):
+        llm_cfg = mcfg.llm
+        compute_dtype = jnp.dtype(llm_cfg.dtype)
+        text_emb = embed_lib.encode(params["llm"]["embed"],
+                                    batch["text_tokens"], compute_dtype)
+        x = jnp.concatenate([media.astype(compute_dtype), text_emb], axis=1)
+        B, T_m = media.shape[0], media.shape[1]
+        T_t = text_emb.shape[1]
+        positions = jnp.broadcast_to(jnp.arange(T_m + T_t)[None],
+                                     (B, T_m + T_t))
+        seg = None
+        if "media_mask" in batch and "text_mask" in batch:
+            m_seg = jnp.ones((B, T_m), jnp.int32)
+            t_seg = jnp.where(batch["text_mask"] > 0, 1, 0).astype(jnp.int32)
+            seg = jnp.concatenate([m_seg, t_seg], axis=1)
+        logits, _, aux = model_lib.forward(params["llm"], llm_cfg, embeds=x,
+                                           positions=positions,
+                                           segment_ids=seg, ctx=ctx)
+        return logits[:, T_m:], aux

@@ -293,12 +293,13 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
     }
     if ctx.return_hidden or not (cfg.has_lm_head and cfg.vocab_size > 0):
         return x, new_caches, aux
-    if cfg.tie_embeddings:
-        logits = embed.decode(params["embed"], x)
-    else:
-        logits = embed.unembed(params["unembed"], x)
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+    with jax.named_scope("dflop.head"):
+        if cfg.tie_embeddings:
+            logits = embed.decode(params["embed"], x)
+        else:
+            logits = embed.unembed(params["unembed"], x)
+        if cfg.logit_softcap:
+            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
     if ctx.logits_constrain is not None:
         logits = ctx.logits_constrain(logits)
     return logits, new_caches, aux

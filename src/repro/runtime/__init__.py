@@ -3,7 +3,8 @@
 Turns the one-shot profile → plan → schedule façade into a closed control
 loop (the paper's "continuously profiles runtime behavior" claim):
 
-  trace       — low-overhead span recorder, Chrome-trace (Perfetto) export
+  trace       — span recorder (``repro.common.trace``, re-exported): Chrome
+                export, and ``dflop.`` spans in the JAX profiler's trace
   metrics     — rolling bubble-fraction / utilization / imbalance counters
   calibration — online per-(module, shape-bucket, tp) EWMA residual model
   drift       — Page–Hinkley + KS drift detection over shapes & residuals
@@ -11,6 +12,7 @@ loop (the paper's "continuously profiles runtime behavior" claim):
 
 Entry point: ``DFLOPEngine.runtime(gbs)`` returns a wired controller.
 """
+from repro.common.trace import TraceRecorder
 from repro.runtime.calibration import OnlineCalibrator, shape_bucket
 from repro.runtime.controller import (
     RecoveryRecord,
@@ -24,7 +26,6 @@ from repro.runtime.drift import (
     ks_distance,
 )
 from repro.runtime.metrics import RollingStat, RuntimeMetrics
-from repro.runtime.trace import TraceRecorder
 
 __all__ = [
     "DriftDetector",

@@ -33,6 +33,7 @@ import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
+from repro.common.trace import TraceRecorder
 from repro.core.optimizer.objective import get_objective
 from repro.core.optimizer.search import ParallelismOptimizer, SearchResult
 from repro.core.profiling.data_profiler import ShapeDistribution
@@ -41,7 +42,6 @@ from repro.data.items import DataItem
 from repro.runtime.calibration import OnlineCalibrator
 from repro.runtime.drift import DriftDetector, DriftEvent
 from repro.runtime.metrics import RuntimeMetrics
-from repro.runtime.trace import TraceRecorder
 
 
 @dataclass
@@ -248,19 +248,23 @@ class RuntimeController:
                 self._on_drift(ev)
 
     def observe_step(self, out: ScheduleOutput, measured_s: float, *,
-                     idle_s: float = 0.0, busy_s: Optional[float] = None,
+                     idle_s: Optional[float] = None,
+                     busy_s: Optional[float] = None,
                      stage_busy=None) -> None:
         """Whole-step feedback: wall time vs. the predicted makespan.
 
-        ``busy_s=None`` means "not measured" (the non-idle remainder of the
-        step is assumed busy); an explicit ``0.0`` is a fully *idle* step
-        and must yield bubble fraction 1.0, not 0.0."""
+        ``idle_s=None`` means the step's idle time was not measured: no
+        bubble fraction is recorded, neither in the metrics nor as a trace
+        counter.  ``busy_s=None`` means "not measured" (the non-idle
+        remainder of the step is assumed busy); an explicit ``0.0`` is a
+        fully *idle* step and must yield bubble fraction 1.0, not 0.0."""
         self.trace.complete("step", self.trace.now_us() - measured_s * 1e6,
                             measured_s * 1e6, cat="step",
                             args={"pred_cmax_s": out.cmax})
         self.metrics.record_step(measured_s, idle_s, busy_s, stage_busy)
-        self.trace.counter("bubble_fraction",
-                           self.metrics.bubble_fraction.last())
+        if idle_s is not None:
+            self.trace.counter("bubble_fraction",
+                               self.metrics.bubble_fraction.last())
         if out.cmax > 0 and measured_s > 0:
             ev = self.drift.observe_residual(abs(measured_s / out.cmax - 1.0))
             if ev is not None:

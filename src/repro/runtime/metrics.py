@@ -127,16 +127,18 @@ class RuntimeMetrics:
         self.pred_cmax_s.add(out.cmax)
         self.n_schedules += 1
 
-    def record_step(self, step_time_s: float, idle_s: float,
+    def record_step(self, step_time_s: float, idle_s: Optional[float],
                     busy_s: Optional[float] = None,
                     stage_busy: Optional[np.ndarray] = None) -> None:
-        """``busy_s=None`` (not measured) defaults to the non-idle
-        remainder of the step; an explicit ``0.0`` means a fully idle step
-        (bubble fraction 1.0) — the two must not be conflated."""
-        if busy_s is None:
-            busy_s = max(step_time_s - idle_s, 0.0)
+        """``idle_s=None`` (not measured) adds no bubble sample.
+        ``busy_s=None`` (not measured) defaults to the non-idle remainder
+        of the step; an explicit ``0.0`` means a fully idle step (bubble
+        fraction 1.0) — the two must not be conflated."""
         self.step_time_s.add(step_time_s)
-        self.bubble_fraction.add(idle_s / max(idle_s + busy_s, 1e-12))
+        if idle_s is not None:
+            if busy_s is None:
+                busy_s = max(step_time_s - idle_s, 0.0)
+            self.bubble_fraction.add(idle_s / max(idle_s + busy_s, 1e-12))
         if stage_busy is not None and step_time_s > 0:
             for p, b in enumerate(np.asarray(stage_busy, dtype=float)):
                 self.stage_util.setdefault(

@@ -57,13 +57,14 @@ def make_loss_fn(desc: ModelDesc, ctx: Optional[FwdCtx] = None,
             logits, aux = mllm_lib.forward_train(params, desc, mb, ctx=ctx,
                                                  communicator=communicator,
                                                  enc_ctx=enc_ctx)
-            if vocab_ce is not None:
-                # with return_hidden, forward_train yields the text-span
-                # hidden states; head + CE run vocab-parallel
-                w, _ = _head_weight(desc.llm, params["llm"])
-                ce = vocab_ce(w, logits, mb["labels"])
-            else:
-                ce = cross_entropy(logits, mb["labels"])
+            with jax.named_scope("dflop.head"):
+                if vocab_ce is not None:
+                    # with return_hidden, forward_train yields the text-span
+                    # hidden states; head + CE run vocab-parallel
+                    w, _ = _head_weight(desc.llm, params["llm"])
+                    ce = vocab_ce(w, logits, mb["labels"])
+                else:
+                    ce = cross_entropy(logits, mb["labels"])
             return finish(ce, aux)
         return loss_fn
 
@@ -73,11 +74,12 @@ def make_loss_fn(desc: ModelDesc, ctx: Optional[FwdCtx] = None,
             out, _, aux = model_lib.forward(
                 params, desc, embeds=mb["frame_embeds"],
                 segment_ids=mb.get("segment_ids"), ctx=ctx)
-            if vocab_ce is not None:
-                w, _ = _head_weight(desc, params)
-                ce = vocab_ce(w, out, mb["labels"])
-            else:
-                ce = cross_entropy(out, mb["labels"])
+            with jax.named_scope("dflop.head"):
+                if vocab_ce is not None:
+                    w, _ = _head_weight(desc, params)
+                    ce = vocab_ce(w, out, mb["labels"])
+                else:
+                    ce = cross_entropy(out, mb["labels"])
             return finish(ce, aux)
         return loss_fn
 
@@ -86,11 +88,12 @@ def make_loss_fn(desc: ModelDesc, ctx: Optional[FwdCtx] = None,
             params, desc, tokens=mb["tokens"],
             positions=mb.get("positions"),
             segment_ids=mb.get("segment_ids"), ctx=ctx)
-        if vocab_ce is not None:
-            w, _ = _head_weight(desc, params)
-            ce = vocab_ce(w, out, mb["labels"])
-        else:
-            ce = cross_entropy(out, mb["labels"])
+        with jax.named_scope("dflop.head"):
+            if vocab_ce is not None:
+                w, _ = _head_weight(desc, params)
+                ce = vocab_ce(w, out, mb["labels"])
+            else:
+                ce = cross_entropy(out, mb["labels"])
         return finish(ce, aux)
     return loss_fn
 
@@ -113,19 +116,23 @@ def make_train_step(desc: ModelDesc, opt_cfg: AdamWConfig,
         def mb_step(carry, mb):
             loss_sum, drop_sum, imb_max, grads = carry
             (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
-            grads = jax.tree.map(
-                lambda a, b: a + b.astype(jnp.float32), grads, g)
+            with jax.named_scope("dflop.grad_accum"):
+                grads = jax.tree.map(
+                    lambda a, b: a + b.astype(jnp.float32), grads, g)
             drop_sum = drop_sum + aux["moe_drop_rate"]
             imb_max = jnp.maximum(imb_max, aux["moe_imbalance"])
             return (loss_sum + l, drop_sum, imb_max, grads), None
 
-        init = (zero, zero, zero,
-                jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params))
+        with jax.named_scope("dflop.grad_accum"):
+            acc0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                                params)
         (loss_sum, drop_sum, imb_max, grads), _ = jax.lax.scan(
-            mb_step, init, batch)
-        grads = jax.tree.map(lambda g: g / n_mb, grads)
-        new_params, new_opt = adamw_update(opt_cfg, params, grads, opt_state,
-                                           lr=lr)
+            mb_step, (zero, zero, zero, acc0), batch)
+        with jax.named_scope("dflop.grad_accum"):
+            grads = jax.tree.map(lambda g: g / n_mb, grads)
+        with jax.named_scope("dflop.optimizer"):
+            new_params, new_opt = adamw_update(opt_cfg, params, grads,
+                                               opt_state, lr=lr)
         # NaN-preserving aggregates (no-MoE models report NaN, never 0.0)
         metrics = {"loss": loss_sum / n_mb,
                    "moe_drop_rate": drop_sum / n_mb,

@@ -325,6 +325,26 @@ def test_controller_no_replan_when_disabled():
     ctl.close()
 
 
+def test_observe_step_without_idle_records_no_bubble():
+    """A step whose idle time nobody measured leaves the bubble fraction
+    unmeasured (None), in the metrics and in the trace; a measured 0.0
+    still records 0.0."""
+    eng = _engine("single_image")
+    eng.plan(32)
+    ctl = eng.runtime(32, adaptive=False, auto_replan=False,
+                      ilp_time_limit_s=0.05)
+    out = ctl.schedule(eng.dataset.sample(32))
+    ctl.observe_step(out, 0.5)
+    snap = ctl.metrics.snapshot()
+    assert snap["n_steps"] == 1 and snap["step_time_mean_s"] == 0.5
+    assert snap["bubble_fraction_mean"] is None
+    assert "bubble_fraction" not in {e[1] for e in ctl.trace._events}
+    ctl.observe_step(out, 0.5, idle_s=0.0)
+    assert ctl.metrics.snapshot()["bubble_fraction_mean"] == 0.0
+    assert "bubble_fraction" in {e[1] for e in ctl.trace._events}
+    ctl.close()
+
+
 def test_controller_observe_feeds_calibration_and_adaptive():
     eng = _engine("single_image")
     eng.plan(32)
