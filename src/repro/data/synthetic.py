@@ -8,9 +8,21 @@ Table 2 (65k / 60k / 60k -> 0.35 / 0.32 / 0.33).
 
 `MixedDataset` yields `DataItem`s (for the scheduler) and can materialize
 tensor batches (stub embeddings + token ids) for actual training.
+
+`materialize` draws each row's stub embeddings in chunks of `_CHUNK_ROWS`
+patch rows, chunk ``j`` of row ``i`` from its own stream
+``SeedSequence(seed, spawn_key=(i, j))`` and the row's text from
+``(i,)``, in float32 straight into the output.  The values depend only on
+the seed, the items and the sizes, never on how many threads fill them: a
+draw of `_INLINE_SAMPLES` or more is spread over a process-wide pool of
+`_WORKERS` threads (numpy releases the GIL while it fills), a smaller one
+stays on the calling thread.
 """
 from __future__ import annotations
 
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -21,6 +33,42 @@ from repro.data.items import DataItem
 
 # records nothing itself: its spans reach only a running profiler session
 _SPANS = TraceRecorder(enabled=False)
+
+_CHUNK_ROWS = 256            # patch rows one stream fills
+_INLINE_SAMPLES = 1 << 20    # a smaller draw stays on the calling thread
+_WORKERS = min(16, len(os.sched_getaffinity(0)))
+_SCALE = np.float32(0.02)    # std of the stub embeddings
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _stream(seed: int, *key: int) -> np.random.Generator:
+    return np.random.Generator(np.random.PCG64(
+        np.random.SeedSequence(seed, spawn_key=key)))
+
+
+def _fill(media: np.ndarray, chunks: Sequence[Tuple[int, int, int]],
+          seed: int) -> None:
+    """Draw N(0, 0.02^2) into ``media[i, a:b]`` for each ``(i, a, b)``."""
+    for i, a, b in chunks:
+        out = media[i, a:b]
+        _stream(seed, i, a // _CHUNK_ROWS).standard_normal(
+            out=out, dtype=np.float32)
+        out *= _SCALE
+
+
+def _draw(media, chunks, seed) -> None:
+    with _SPANS.span("draw", cat="data"):
+        _fill(media, chunks, seed)
+
+
+def _executor() -> ThreadPoolExecutor:
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(_WORKERS,
+                                       thread_name_prefix="materialize")
+        return _pool
 
 
 @dataclass(frozen=True)
@@ -86,26 +134,39 @@ class MixedDataset:
     def materialize(self, items: Sequence[DataItem], *, embed_dim: int,
                     vocab_size: int, max_media: int, max_text: int,
                     seed: int = 0) -> dict:
-        """Tensorize items into a padded multimodal batch (stub frontend)."""
+        """Tensorize items into a padded multimodal batch (stub frontend):
+        float32 N(0, 0.02^2) embeddings in each row's media slots, ids
+        uniform in ``[1, vocab_size)`` in its text slots, zeros in padding,
+        next-token labels (-1 where none)."""
         with _SPANS.span("materialize", cat="data"):
-            rng = np.random.default_rng(seed)
             B = len(items)
-            t_media = max_media
-            media = np.zeros((B, t_media, embed_dim), np.float32)
-            media_mask = np.zeros((B, t_media), np.int32)
+            media = np.zeros((B, max_media, embed_dim), np.float32)
+            media_mask = np.zeros((B, max_media), np.int32)
             text = np.zeros((B, max_text), np.int32)
             text_mask = np.zeros((B, max_text), np.int32)
             labels = np.full((B, max_text), -1, np.int32)
             tpm = self.tokens_per_media_item
-            for i, it in enumerate(items):
-                m = min(it.n_media_items * tpm, t_media)
-                media[i, :m] = rng.standard_normal((m, embed_dim)) * 0.02
+            ms = [min(it.n_media_items * tpm, max_media) for it in items]
+            chunks = [(i, a, min(a + _CHUNK_ROWS, m))
+                      for i, m in enumerate(ms)
+                      for a in range(0, m, _CHUNK_ROWS)]
+            tasks = min(_WORKERS, len(chunks))
+            if sum(ms) * embed_dim < _INLINE_SAMPLES or tasks < 2:
+                _fill(media, chunks, seed)
+                pending = []
+            else:
+                pool = _executor()
+                pending = [pool.submit(_draw, media, chunks[w::tasks], seed)
+                           for w in range(tasks)]
+            for i, (it, m) in enumerate(zip(items, ms)):
                 media_mask[i, :m] = 1
                 t = min(it.text_len, max_text)
-                toks = rng.integers(1, vocab_size, size=t)
+                toks = _stream(seed, i).integers(1, vocab_size, size=t)
                 text[i, :t] = toks
                 text_mask[i, :t] = 1
-                labels[i, : t - 1] = toks[1:]
+                labels[i, :max(t - 1, 0)] = toks[1:]
+            for f in pending:
+                f.result()
             return {
                 "media_embeds": media,
                 "media_mask": media_mask,

@@ -162,12 +162,18 @@ def test_compiled_train_step_carries_every_scope():
         assert any(k[0] == m for k in seen), m
 
 
-def test_trace_recorder_spans_reach_the_profiler(tmp_path):
+def test_trace_recorder_spans_reach_the_profiler(tmp_path, monkeypatch):
     """``TraceRecorder.span`` and ``MixedDataset.materialize`` write
     ``dflop.`` host events into a profiler trace, on the calling thread and
-    on the profiler's clock, whether or not the recorder is enabled."""
+    on the profiler's clock, whether or not the recorder is enabled.  A
+    draw large enough for the pool adds one ``dflop.data.draw`` a worker
+    task, on the workers' threads, inside its ``materialize``."""
+    from repro.data import synthetic
+    monkeypatch.setattr(synthetic, "_WORKERS", 3)
+    monkeypatch.setattr(synthetic, "_pool", None)
     quiet, loud = TraceRecorder(enabled=False), TraceRecorder()
     ds = MixedDataset("single_image", seed=0, tokens_per_media_item=4)
+    big = MixedDataset("single_image", seed=0, tokens_per_media_item=4096)
     jax.profiler.start_trace(str(tmp_path))
     try:
         with jax.profiler.TraceAnnotation("bench.window"):
@@ -177,8 +183,12 @@ def test_trace_recorder_spans_reach_the_profiler(tmp_path):
                 pass
             ds.materialize([DataItem(1, 8)], embed_dim=8, vocab_size=64,
                            max_media=8, max_text=16)
+            # 2 rows of 4096 x 128: 1.05 M samples in 32 chunks, 3 tasks
+            big.materialize([DataItem(1, 8)] * 2, embed_dim=128,
+                            vocab_size=64, max_media=4096, max_text=16)
     finally:
         jax.profiler.stop_trace()
+        synthetic._pool.shutdown(wait=True)
     path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                      recursive=True)[0]
     spans, _, _ = ts.read_events(path)
@@ -189,4 +199,9 @@ def test_trace_recorder_spans_reach_the_profiler(tmp_path):
         a, b, t = by_name[n]
         assert t == thread and w0 <= a <= b <= w1, n
     assert len(quiet) == 0 and len(loud) == 1
-    assert ts.host_spans(spans)["dflop.data.materialize"]["count"] == 1
+    host = ts.host_spans(spans)
+    assert host["dflop.data.materialize"]["count"] == 2
+    assert host["dflop.data.draw"]["count"] == 3
+    m0, m1, _ = by_name["dflop.data.materialize"]      # the big one
+    draws = [(a, b, t) for n, a, b, t in spans if n == "dflop.data.draw"]
+    assert all(m0 <= a <= b <= m1 and t != thread for a, b, t in draws)
