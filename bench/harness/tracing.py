@@ -1,4 +1,5 @@
-"""The profiler around a traced run's window, and the reduction of its trace."""
+"""The profiler around a traced run's window, and the reduction of its trace
+against the compiled step's HLO (``bench/trace_reduce.py``)."""
 from __future__ import annotations
 
 import glob
@@ -16,8 +17,12 @@ TRACE_SECONDS = 10.0
 
 
 class Tracer:
-    def __init__(self, on: bool):
-        self.on, self.result, self.dir = on, None, None
+    """Traces the window where given the step's optimized HLO text
+    (``compiled.as_text()``); does nothing given None."""
+
+    def __init__(self, hlo_text: str | None):
+        self.hlo_text, self.result, self.dir = hlo_text, None, None
+        self.on = hlo_text is not None
 
     def __enter__(self) -> "Tracer":
         if self.on:
@@ -35,6 +40,7 @@ class Tracer:
                                   recursive=True)
                 if not files:
                     raise RuntimeError("the profiler wrote no trace")
-                self.result = trace_reduce.reduce_file(files[0])
+                self.result = trace_reduce.reduce_file(files[0],
+                                                       self.hlo_text)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)

@@ -9,24 +9,31 @@ cell's seconds:
       -> device_put -> compiled make_train_step -> observe_step
 
 Each step ends in ``block_until_ready``.  After the window the program's
-state is freed and the plain reference (``bench/reference``) follows the
-driven steps on the same weights and batches.
+state is freed and the plain reference follows the driven steps on the same
+weights and batches.  The configuration file names everything of its model:
+``model`` the program's ``MLLMConfig``, ``reference`` the module under
+``bench/reference/`` that makes the weights, checks the rows, follows the
+steps and counts the FLOPs a step requires.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
+import importlib
 import time
+import typing
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import flops as flops_lib
 from bench.harness import check, tracing
 from bench.harness.traffic import Traffic
-from bench.reference import mllm as ref
 
 DRIVEN_STEPS = 3
+# what the harness takes from a reference module
+REFERENCE_API = ("seed_key", "init_params", "Reference", "next_token_labels",
+                 "leaf_norms", "leaf_names", "step_flops")
 _COMPILES = [0]
 
 
@@ -42,20 +49,43 @@ def _span(name: str, **kw):
     return jax.profiler.TraceAnnotation(f"bench.{name}", **kw)
 
 
+def _build(cls, values: dict, where: str):
+    """``cls`` from the file's ``values``: every key a field of ``cls``, a
+    field the file lacks at its default; nested dataclasses built alike,
+    lists made tuples."""
+    fields = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(values) - fields)
+    if unknown:
+        raise ValueError(f"{where}: {', '.join(unknown)} not a field of the "
+                         f"program's {cls.__name__}")
+    hints = typing.get_type_hints(cls)
+    kw = {}
+    for key, v in values.items():
+        if dataclasses.is_dataclass(hints[key]):
+            v = _build(hints[key], v, f"{where}.{key}")
+        elif isinstance(v, list):
+            v = tuple(v)
+        kw[key] = v
+    return cls(**kw)
+
+
 def to_desc(m: dict):
-    """The program's MLLMConfig from the configuration file's ``model``."""
-    from repro.common.types import MLLMConfig, ModalityStub, ModelConfig
+    """The program's MLLMConfig from the configuration file's ``model``.
+    A key that no field of the program's config has stops the run with
+    its name."""
+    from repro.common.types import MLLMConfig
+    return _build(MLLMConfig, m, "model")
 
-    def stack(c):
-        c = dict(c)
-        c["layer_pattern"] = tuple(c["layer_pattern"])
-        c["ffn_pattern"] = tuple(c["ffn_pattern"])
-        return ModelConfig(**c)
 
-    return MLLMConfig(name=m["name"], encoder=stack(m["encoder"]),
-                      llm=stack(m["llm"]), stub=ModalityStub(**m["stub"]),
-                      connector_hidden=m["connector_hidden"],
-                      tokens_per_item_out=m["tokens_per_item_out"])
+def reference_of(cfg: dict):
+    """The plain reference the configuration names (``bench/reference/``),
+    with every name of ``REFERENCE_API``."""
+    mod = importlib.import_module(f"bench.reference.{cfg['reference']}")
+    missing = [n for n in REFERENCE_API if not callable(getattr(mod, n, None))]
+    if missing:
+        raise ValueError(f"reference {cfg['reference']!r} lacks "
+                         f"{', '.join(missing)}")
+    return mod
 
 
 class TrainRun:
@@ -66,6 +96,7 @@ class TrainRun:
         self.m = cfg["model"]
         self.opt_cfg = cfg["optimizer"]
         self.desc = to_desc(self.m)
+        self.ref = reference_of(cfg)
         self.dev = jax.devices()[0]
         self.traffic = Traffic(traffic, seed, self.desc.tokens_per_item_out)
         self.n_mb = int(traffic["microbatches"])
@@ -73,7 +104,7 @@ class TrainRun:
         self.media_cap = int(traffic["media_cap"])
         self.text_cap = int(traffic["text_cap"])
         self.t_media = self.media_cap * self.desc.stub.n_tokens
-        self.flops_per_step = flops_lib.step_flops(
+        self.flops_per_step = self.ref.step_flops(
             self.m, self.n_mb * self.rows, self.t_media, self.text_cap)
 
     # ------------------------------------------------------------------ #
@@ -105,8 +136,8 @@ class TrainRun:
         self.ds = MixedDataset(dict(self.spec["mixture"]), seed=0,
                                tokens_per_media_item=desc.stub.n_tokens)
 
+        ref, m = self.ref, self.m
         self.key = ref.seed_key(self.seed)
-        m = self.m
         self.init = jax.jit(lambda k: ref.init_params(k, m))
         want = jax.tree.structure(jax.eval_shape(
             lambda k: mllm_lib.init(k, desc), jax.random.PRNGKey(0)))
@@ -169,7 +200,9 @@ class TrainRun:
     def materialize(self, k: int, layout):
         """The program's ``materialize`` over the rows of each microbatch.
         A row left empty (a group with fewer items than rows) is all
-        padding; ``materialize`` cannot make one (it fails on no text)."""
+        padding.  ``materialize`` would make such a row the same padding,
+        but it is given only the rows that hold text, each drawn from the
+        streams of its index among them."""
         n_tok, tpo = self.desc.stub.n_tokens, self.desc.tokens_per_item_out
         pad = {key: np.zeros(s.shape[2:], s.dtype)
                for key, s in self.batch_shapes().items()}
@@ -198,7 +231,7 @@ class TrainRun:
                     it.n_media_items * n_tok, self.t_media))
                 bad += int(mb["text_mask"][r].sum() != text)
                 bad += int(not np.array_equal(mb["labels"][r],
-                                              ref.next_token_labels(
+                                              self.ref.next_token_labels(
                                                   mb["text_tokens"][r],
                                                   mb["text_mask"][r])))
             mbs.append(mb)
@@ -258,6 +291,8 @@ class TrainRun:
                 failed += int(bad)
                 steps.append({**counts, "step_s": step_s,
                               "pred_s": float(out.step_makespan),
+                              "pred_enc_s": float(out.e_dur.sum()),
+                              "pred_llm_s": float(out.l_dur.sum()),
                               "flops": self.flops_per_step,
                               "t_end": t_end - t_w0})
                 k += 1
@@ -284,11 +319,11 @@ class TrainRun:
 
     def reference(self, precision: str = "highest") -> dict:
         """The reference's readings over the driven steps' batches."""
-        return ref.Reference(self.m, self.opt_cfg, precision).run(
+        return self.ref.Reference(self.m, self.opt_cfg, precision).run(
             self.key, self.ref_batches(), self.init)
 
     def leaf_names(self) -> list[str]:
-        return ref.leaf_names(jax.eval_shape(self.init, self.key))
+        return self.ref.leaf_names(jax.eval_shape(self.init, self.key))
 
 
 def run_cell(cell: dict, cfg: dict, traffic: dict, limits: dict, *,
@@ -303,7 +338,7 @@ def run_cell(cell: dict, cfg: dict, traffic: dict, limits: dict, *,
     prog = run.drive(log)
     if trace:
         seconds = min(seconds, tracing.TRACE_SECONDS)
-    with tracing.Tracer(trace) as tr:
+    with tracing.Tracer(run.step.as_text() if trace else None) as tr:
         setup_s = time.perf_counter() - t_start
         win = run.window(seconds, DRIVEN_STEPS)
     log(f"[window] {len(win['steps'])} steps in {win['window_s']:.3f} s, "

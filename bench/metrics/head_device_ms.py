@@ -1,0 +1,9 @@
+"""Mean device milliseconds a step in the LM head and the loss (``dflop.head``:
+unembedding and cross-entropy, forward and backward), from the traced
+window's scope reduction (``bench/trace_reduce.py``)."""
+from bench.trace_reduce import module_time
+
+
+def read(run: dict):
+    got = module_time(run.get("trace"), "head")
+    return None if got is None else 1e3 * got[0] / got[1]

@@ -20,6 +20,13 @@ with decoupled weight decay on every leaf of two or more dimensions.
 reference, ``"fp8"`` rounds both operands of each forward product to
 float8 e4m3 with a per-tensor scale (the control, one step below the
 bfloat16 the configuration states).
+
+A configuration file names its reference module (``"reference": "mllm"``);
+the harness takes from it ``seed_key``, ``init_params``, ``Reference``,
+``next_token_labels``, ``leaf_norms``, ``leaf_names`` and
+``step_flops(m, n_rows, t_media, t_text)``, the FLOPs a step of this model
+requires (``bench/flops.py``, which counts the LLM over the whole-row
+pooled length this module computes).
 """
 from __future__ import annotations
 
@@ -29,6 +36,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.flops import step_flops  # noqa: F401  (the harness reads it here)
 
 NEG_INF = -1e30
 Q_BLOCK = 512            # query rows per attention block (memory only)

@@ -8,7 +8,8 @@ cell, its configuration file and its traffic mix
 (``bench/traffic/<traffic>.json``); the limits of its check are in
 ``bench/limits/<cell>.json``; each metric is read by
 ``bench/metrics/<metric>.py``; the configuration's ``driver`` names the
-module under ``bench/harness/`` that runs it.
+module under ``bench/harness/`` that runs it, and its ``reference`` the plain
+reference under ``bench/reference/`` that the run is checked against.
 
 With ``--trace 0`` the last line of stdout is the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics, read from a profiler trace of the

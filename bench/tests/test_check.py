@@ -16,8 +16,9 @@ import pytest
 
 from bench import run as bench_run
 from bench.harness import train_1chip
-from bench.reference import mllm as ref
 from bench.tests import tiny
+
+ref = train_1chip.reference_of(tiny.CONFIG)
 
 SEED = 2 ** 33 + 17
 

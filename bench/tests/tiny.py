@@ -35,7 +35,7 @@ MODEL = {
 }
 
 CONFIG = {"name": "tiny-1chip", "source": "test", "driver": "train_1chip",
-          "reduced": {}, "model": MODEL,
+          "reference": "mllm", "reduced": {}, "model": MODEL,
           "optimizer": {"lr": 1e-3, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
                         "weight_decay": 0.1, "grad_clip": 1.0}}
 
@@ -53,22 +53,24 @@ LIMITS = {"loss_gap": {"limit": 0.012}, "grad_gap": {"limit": 0.02},
           "update_gap": {"limit": 0.006}}
 
 
-def write_checkout(root: Path, limits: dict = LIMITS) -> str:
+def write_checkout(root: Path, limits: dict = LIMITS,
+                   config: dict = CONFIG) -> str:
     """A checkout holding one tiny cell, made of data files only; returns
     the cell's name."""
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "limits").mkdir()
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    bench["configs"] = [{"name": "tiny-1chip", "source": "test",
-                         "file": "bench/configs/tiny-1chip.json",
+    name = config["name"]
+    bench["configs"] = [{"name": name, "source": "test",
+                         "file": f"bench/configs/{name}.json",
                          "reduced": [], "why": "test"}]
-    bench["workloads"] = [{"name": "tiny.mix", "config": "tiny-1chip",
+    bench["workloads"] = [{"name": "tiny.mix", "config": name,
                            "traffic": "tinymix", "chips": 1, "why": "test"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    (root / "bench/configs/tiny-1chip.json").write_text(json.dumps(CONFIG))
+    (root / f"bench/configs/{name}.json").write_text(json.dumps(config))
     (root / "bench/traffic/tinymix.json").write_text(json.dumps(TRAFFIC))
     (root / "bench/limits/tiny.mix.json").write_text(json.dumps(limits))
     return "tiny.mix"

@@ -3,13 +3,13 @@
     python bench/tools/calibrate.py --workload ivl2.mixed --seeds 12 \
         --faults 3 --out readings.jsonl
 
-For each seed: the program's driven steps against the reference (the lower
-readings).  For the first ``--faults`` seeds also the control, the
-reference computed with float8 products put in the program's place, and the
-fault "half the batch left out", planted in the reference (the upper
-readings).  A step that returns its state unchanged reads 1 on
-``update_gap`` and ``grad_gap`` by construction and needs no run.  One JSON
-line per seed; no measured window.
+For each seed: the program's driven steps against the reference that the
+cell's configuration names (the lower readings).  For the first
+``--faults`` seeds also the control, the reference computed with float8
+products put in the program's place, and the fault "half the batch left
+out", planted in the reference (the upper readings).  A step that returns
+its state unchanged reads 1 on ``update_gap`` and ``grad_gap`` by
+construction and needs no run.  One JSON line per seed; no measured window.
 """
 from __future__ import annotations
 
@@ -42,14 +42,14 @@ def main() -> None:
 
     import jax
     from bench.harness import check
-    from bench.harness.train_1chip import TrainRun
-    from bench.reference import mllm as ref
+    from bench.harness.train_1chip import TrainRun, reference_of
     from repro.common import compile_cache
 
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("calibrate: needs a TPU")
     compile_cache.enable()
     _, _, cfg, traffic, _ = bench_run.load_cell(ROOT, args.workload)
+    ref = reference_of(cfg)
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
     refs = {}
