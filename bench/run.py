@@ -8,8 +8,9 @@ cell, its configuration file and its traffic mix
 (``bench/traffic/<traffic>.json``); the limits of its check are in
 ``bench/limits/<cell>.json``; each metric is read by
 ``bench/metrics/<metric>.py``; the configuration's ``driver`` names the
-module under ``bench/harness/`` that runs it, and its ``reference`` the plain
-reference under ``bench/reference/`` that the run is checked against.
+module under ``bench/harness/`` that runs it, its ``reference`` the plain
+reference under ``bench/reference/`` that the run is checked against, and
+its ``mesh``, where it has one, how the cell's chips are laid out.
 
 With ``--trace 0`` the last line of stdout is the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics, read from a profiler trace of the
@@ -120,6 +121,9 @@ def execute(root: Path, workload: str, *, seed: int, seconds: float,
         device.update(busy_s=tr["busy_s"], window_s=tr["window_s"])
         line["breakdown"] = {"device_ops": tr["device_ops"],
                              "idle_gaps": tr["idle_gaps"]}
+        log(f"[trace] devices {tr['devices']}, busy_s {tr['busy_s']!r}, "
+            f"collective_s {tr['collective_s']!r}, collective_exposed_s "
+            f"{tr['collective_exposed_s']!r} (each a device's mean)")
     line["checks"] = res["checks"]
     for name, c in res["checks"].items():
         log(f"[check] {name} {c['value']!r} limit {c['limit']!r}")

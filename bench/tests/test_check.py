@@ -15,10 +15,10 @@ import numpy as np
 import pytest
 
 from bench import run as bench_run
-from bench.harness import train_1chip
+from bench.harness import train
 from bench.tests import tiny
 
-ref = train_1chip.reference_of(tiny.CONFIG)
+ref = train.reference_of(tiny.CONFIG)
 
 SEED = 2 ** 33 + 17
 
@@ -85,13 +85,13 @@ def test_fault_is_caught(tmp_path, monkeypatch, fault):
 
 def test_control_is_caught(tmp_path, monkeypatch):
     """The reference in float8 in the program's place fails the check."""
-    real = train_1chip.TrainRun.drive
+    real = train.TrainRun.drive
 
     def control(self, log):
         real(self, log)
         return {**self.reference("fp8"), "failed": 0}
 
-    monkeypatch.setattr(train_1chip.TrainRun, "drive", control)
+    monkeypatch.setattr(train.TrainRun, "drive", control)
     line = _run(tmp_path)
     assert not line["correct"], line["checks"]
 

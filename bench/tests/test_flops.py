@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from bench import flops
-from bench.harness.train_1chip import to_desc
+from bench.harness.train import to_desc
 from bench.tests import tiny
 
 

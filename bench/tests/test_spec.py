@@ -12,7 +12,7 @@ import pytest
 
 import bench.reference
 from bench import run as bench_run
-from bench.harness.train_1chip import REFERENCE_API, reference_of, to_desc
+from bench.harness.train import REFERENCE_API, reference_of, to_desc
 from bench.harness.traffic import Traffic
 from bench.tests import tiny
 
@@ -25,13 +25,16 @@ CONFIG_FILES = sorted((REPO / "bench/configs").glob("*.json"))
 def test_every_name_resolves_to_a_file():
     """Each cell's configuration, driver, reference (with the API the
     harness calls), registered architecture, traffic and limits; each
-    metric's reader."""
+    metric's reader.  A configuration's ``mesh``, where present, multiplies
+    out to the cell's ``chips``; without one the cell is one chip."""
     from repro.configs import get_config
     configs = {c["name"]: c for c in BENCH["configs"]}
     for cell in BENCH["workloads"]:
         assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
         cfg = json.loads((REPO / configs[cell["config"]]["file"]).read_text())
         assert (REPO / "bench/harness" / f"{cfg['driver']}.py").is_file()
+        mesh = cfg.get("mesh", {"data": 1, "model": 1})
+        assert mesh["data"] * mesh["model"] == cell["chips"], cell["name"]
         ref = reference_of(cfg)
         assert all(callable(getattr(ref, n)) for n in REFERENCE_API)
         assert get_config(cfg["arch"]).desc.name == cfg["arch"]

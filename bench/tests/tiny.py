@@ -34,7 +34,7 @@ MODEL = {
     "tokens_per_item_out": 4,
 }
 
-CONFIG = {"name": "tiny-1chip", "source": "test", "driver": "train_1chip",
+CONFIG = {"name": "tiny-1chip", "source": "test", "driver": "train",
           "reference": "mllm", "reduced": {}, "model": MODEL,
           "optimizer": {"lr": 1e-3, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
                         "weight_decay": 0.1, "grad_clip": 1.0}}
@@ -54,9 +54,9 @@ LIMITS = {"loss_gap": {"limit": 0.012}, "grad_gap": {"limit": 0.02},
 
 
 def write_checkout(root: Path, limits: dict = LIMITS,
-                   config: dict = CONFIG) -> str:
-    """A checkout holding one tiny cell, made of data files only; returns
-    the cell's name."""
+                   config: dict = CONFIG, chips: int = 1) -> str:
+    """A checkout holding one tiny cell on ``chips`` chips, made of data
+    files only; returns the cell's name."""
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "limits").mkdir()
@@ -66,7 +66,7 @@ def write_checkout(root: Path, limits: dict = LIMITS,
                          "file": f"bench/configs/{name}.json",
                          "reduced": [], "why": "test"}]
     bench["workloads"] = [{"name": "tiny.mix", "config": name,
-                           "traffic": "tinymix", "chips": 1, "why": "test"}]
+                           "traffic": "tinymix", "chips": chips, "why": "test"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))

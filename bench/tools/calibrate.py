@@ -3,8 +3,9 @@
     python bench/tools/calibrate.py --workload ivl2.mixed --seeds 12 \
         --faults 3 --out readings.jsonl
 
-For each seed: the program's driven steps against the reference that the
-cell's configuration names (the lower readings).  For the first
+For each seed: the program's driven steps, on the cell's chips through the
+driver that the cell's configuration names, against the reference that it
+names (the lower readings).  For the first
 ``--faults`` seeds also the control, the reference computed with float8
 products put in the program's place, and the fault "half the batch left
 out", planted in the reference (the upper readings).  A step that returns
@@ -14,6 +15,7 @@ construction and needs no run.  One JSON line per seed; no measured window.
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -42,21 +44,24 @@ def main() -> None:
 
     import jax
     from bench.harness import check
-    from bench.harness.train_1chip import TrainRun, reference_of
     from repro.common import compile_cache
 
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("calibrate: needs a TPU")
     compile_cache.enable()
-    _, _, cfg, traffic, _ = bench_run.load_cell(ROOT, args.workload)
-    ref = reference_of(cfg)
+    _, cell, cfg, traffic, _ = bench_run.load_cell(ROOT, args.workload)
+    if len(jax.devices()) < cell["chips"]:
+        raise SystemExit(f"calibrate: {args.workload} needs {cell['chips']} "
+                         f"chips, found {len(jax.devices())}")
+    driver = importlib.import_module(f"bench.harness.{cfg['driver']}")
+    ref = driver.reference_of(cfg)
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
     refs = {}
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
         t0 = time.perf_counter()
-        tr = TrainRun(cfg, traffic, seed)
+        tr = driver.TrainRun(cfg, traffic, seed, cell["chips"])
         tr.setup()
         prog = tr.drive(lambda m: print(m, file=sys.stderr, flush=True))
         tr.free()
