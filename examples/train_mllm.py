@@ -209,9 +209,8 @@ def main():
     composer = ctl.composer
 
     lr_fn = cosine_lr(1e-3, warmup=20, total=args.steps)
-    step = jax.jit(make_train_step(
-        mcfg, AdamWConfig(lr=1e-3),
-        ctx=FwdCtx(mode="train", attn_impl="chunked")))
+    step = jax.jit(make_train_step(mcfg, AdamWConfig(lr=1e-3),
+                                   ctx=FwdCtx(mode="train")))
 
     hsrc = None
     if fleet is not None:

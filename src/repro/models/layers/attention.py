@@ -7,8 +7,11 @@ Three interchangeable implementations of the same math:
                   (q-block, kv-block) tiles with online softmax.  This is the
                   implementation the multi-pod dry-run lowers (bounded memory
                   at 32k sequence length, no Pallas custom-calls on CPU).
-  * ``pallas``  — the TPU Pallas kernel (``repro.kernels.packed_flash_attention``),
-                  validated in interpret mode against ``naive``.
+  * ``pallas``  — the TPU Pallas kernel (``repro.kernels.packed_flash_attention``):
+                  bfloat16 MXU operands, empty tiles skipped; the one-chip
+                  train step's default on a TPU
+                  (``repro.models.model.resolve_attn_impl``), validated in
+                  interpret mode against ``naive``.
 
 Segment-id masking implements the paper's sequence packing (§3.2.1):
 "Attention operations ... must process each original instance separately to
@@ -362,13 +365,16 @@ def apply(params, x, cfg: ModelConfig, *, positions=None, segment_ids=None,
     Train/prefill: cache is None, x is (B,S,d).
     Decode: cache is the layer cache, x is (B,1,d), decode_pos a scalar
     or a (B,) array of per-row positions (continuous batching).
-    Returns (y, new_cache).
+    Returns (y, new_cache, skip): ``skip`` is the float32 pair (skipped,
+    total) of score elements (query × key × head) of the Pallas kernel's
+    grid, zeros on every other path.
     """
     B, S, d = x.shape
     window = cfg.window_size if cfg.attention_kind == "sliding" else 0
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(x.dtype))
+    skip = jnp.zeros((2,), jnp.float32)
 
     if cache is not None:
         pos = check_decode_pos(decode_pos, B)
@@ -394,6 +400,8 @@ def apply(params, x, cfg: ModelConfig, *, positions=None, segment_ids=None,
                 out = kops.packed_flash_attention(
                     q, k, v, segment_ids=segment_ids, causal=cfg.causal,
                     window=window)
+                skip = kops.attention_skip(q, k, segment_ids,
+                                           causal=cfg.causal, window=window)
             else:
                 out = flash_attention_xla(q, k, v, causal=cfg.causal,
                                           window=window, seg_q=segment_ids,
@@ -401,4 +409,4 @@ def apply(params, x, cfg: ModelConfig, *, positions=None, segment_ids=None,
                                           block_q=block, block_k=block)
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
-    return y, cache
+    return y, cache, skip

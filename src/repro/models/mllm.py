@@ -54,7 +54,8 @@ def apply_connector(params, h, mcfg: MLLMConfig):
 
 def encode_media(params, mcfg: MLLMConfig, media_embeds, media_mask=None,
                  ctx: Optional[FwdCtx] = None, communicator=None):
-    """Encoder + connector. Returns LLM-space media tokens (B, T_out, dl).
+    """Encoder + connector. Returns LLM-space media tokens (B, T_out, dl)
+    and the encoder forward's aux dict.
 
     `ctx` here is the ENCODER's forward context (the encoder may carry its
     own sharding constraints under DFLOP's heterogeneous plans)."""
@@ -65,9 +66,9 @@ def encode_media(params, mcfg: MLLMConfig, media_embeds, media_mask=None,
         # (multi-image packing can supply richer ids via media_mask directly.)
         seg = media_mask.astype(jnp.int32)
     with jax.named_scope("dflop.encoder"):
-        h, _, _ = model_lib.forward(params["encoder"], mcfg.encoder,
-                                    embeds=media_embeds, segment_ids=seg,
-                                    ctx=ctx)
+        h, _, aux = model_lib.forward(params["encoder"], mcfg.encoder,
+                                      embeds=media_embeds, segment_ids=seg,
+                                      ctx=ctx)
     with jax.named_scope("dflop.connector"):
         if communicator is not None:
             # Inter-model Communicator: reshard encoder output from the
@@ -81,19 +82,20 @@ def encode_media(params, mcfg: MLLMConfig, media_embeds, media_mask=None,
                 b, _, d = h.shape
                 h = h[:, : (t_in // factor) * factor]
                 h = h.reshape(b, t_in // factor, factor, d).mean(axis=2)
-    return h
+    return h, aux
 
 
 def forward_train(params, mcfg: MLLMConfig, batch, ctx: Optional[FwdCtx] = None,
                   communicator=None, enc_ctx: Optional[FwdCtx] = None):
-    """Full multimodal forward: returns (logits over text span, aux).
+    """Full multimodal forward: returns (logits over text span, aux); the
+    aux is the LLM's, with ``attn_skip`` summed over encoder and LLM.
 
     `enc_ctx` (optional) carries encoder-specific sharding constraints —
     DFLOP's independent per-module parallelism."""
     ctx = ctx or FwdCtx(mode="train")
-    media = encode_media(params, mcfg, batch["media_embeds"],
-                         batch.get("media_mask"), ctx=enc_ctx or ctx,
-                         communicator=communicator)
+    media, enc_aux = encode_media(params, mcfg, batch["media_embeds"],
+                                  batch.get("media_mask"), ctx=enc_ctx or ctx,
+                                  communicator=communicator)
     with jax.named_scope("dflop.llm"):
         llm_cfg = mcfg.llm
         compute_dtype = jnp.dtype(llm_cfg.dtype)
@@ -112,4 +114,5 @@ def forward_train(params, mcfg: MLLMConfig, batch, ctx: Optional[FwdCtx] = None,
         logits, _, aux = model_lib.forward(params["llm"], llm_cfg, embeds=x,
                                            positions=positions,
                                            segment_ids=seg, ctx=ctx)
+        aux = {**aux, "attn_skip": aux["attn_skip"] + enc_aux["attn_skip"]}
         return logits[:, T_m:], aux

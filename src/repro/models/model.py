@@ -31,8 +31,9 @@ class FwdCtx:
     """Per-call forward options (static except decode_pos)."""
 
     mode: str = "train"              # train | prefill | decode
-    attn_impl: str = "chunked"       # naive | chunked | pallas
-    attn_block: int = 512            # flash (block_q, block_k) tile
+    attn_impl: Optional[str] = None  # naive | chunked | pallas; None:
+                                     # resolve_attn_impl picks
+    attn_block: int = 512            # chunked path's (block_q, block_k) tile
     ssm_impl: str = "xla"            # xla | pallas
     moe_impl: str = "capacity"       # dense | capacity
     capacity_factor: float = 2.0
@@ -48,6 +49,19 @@ class FwdCtx:
     return_hidden: bool = False      # skip the LM head (vocab-parallel CE)
     decode_pos: Any = None           # traced scalar in decode mode
     remat: bool = True
+
+
+def resolve_attn_impl(ctx: FwdCtx) -> str:
+    """The attention implementation ``ctx`` runs: its own ``attn_impl``
+    when set, else the Pallas kernel for a one-device train step on a TPU
+    (a Pallas call under GSPMD would need a ``shard_map`` wrapper, so a
+    step with ``shard_ctx`` keeps XLA's), else the chunked XLA path."""
+    if ctx.attn_impl is not None:
+        return ctx.attn_impl
+    if (jax.default_backend() == "tpu" and ctx.mode == "train"
+            and ctx.shard_ctx is None):
+        return "pallas"
+    return "chunked"
 
 
 # --------------------------------------------------------------------------- #
@@ -76,18 +90,20 @@ def _layer_init(key, cfg: ModelConfig, kind: LayerKind, ffn_kind: FFNKind,
 
 def _layer_apply(lp, x, cfg: ModelConfig, kind: LayerKind, ffn_kind: FFNKind,
                  ctx: FwdCtx, cache, positions, segment_ids):
-    """Returns (x, new_cache, lb, moe_stats); moe_stats is None for
-    non-MoE layers and a (drop_rate, imbalance) pair (possibly NaN —
-    shard_map dispatch doesn't measure) for MoE layers."""
+    """Returns (x, new_cache, lb, moe_stats, attn_skip); moe_stats is None
+    for non-MoE layers and a (drop_rate, imbalance) pair (possibly NaN —
+    shard_map dispatch doesn't measure) for MoE layers; attn_skip is the
+    attention's (skipped, total) score elements (zeros without one)."""
     lb = jnp.zeros((), jnp.float32)
     moe_stats = None
+    skip = jnp.zeros((2,), jnp.float32)
     h = norms.rms_apply(lp["ln1"], x, cfg.norm_eps)
     if kind == LayerKind.ATTENTION:
         attn_cache = cache.get("attn") if cache else None
-        y, new_attn_cache = attention.apply(
+        y, new_attn_cache, skip = attention.apply(
             lp["attn"], h, cfg, positions=positions, segment_ids=segment_ids,
-            cache=attn_cache, decode_pos=ctx.decode_pos, impl=ctx.attn_impl,
-            block=ctx.attn_block)
+            cache=attn_cache, decode_pos=ctx.decode_pos,
+            impl=resolve_attn_impl(ctx), block=ctx.attn_block)
         new_cache = {"attn": new_attn_cache} if cache else None
     elif kind == LayerKind.MAMBA:
         m_cache = cache.get("mamba") if cache else None
@@ -110,7 +126,7 @@ def _layer_apply(lp, x, cfg: ModelConfig, kind: LayerKind, ffn_kind: FFNKind,
         h2 = norms.rms_apply(lp["ln2"], x, cfg.norm_eps)
         y2, new_r2 = rwkv6.channel_mix(lp["rwkv"], h2, cfg, cache=new_r)
         new_cache = {"rwkv": new_r2} if cache else None
-        return x + y2, new_cache, lb, moe_stats
+        return x + y2, new_cache, lb, moe_stats, skip
     else:
         raise ValueError(kind)
     x = x + y
@@ -125,7 +141,7 @@ def _layer_apply(lp, x, cfg: ModelConfig, kind: LayerKind, ffn_kind: FFNKind,
                      jax.lax.stop_gradient(st["imbalance"]))
     else:
         y2 = ffn.apply(lp["ffn"], h2, cfg)
-    return x + y2, new_cache, lb, moe_stats
+    return x + y2, new_cache, lb, moe_stats, skip
 
 
 # --------------------------------------------------------------------------- #
@@ -207,7 +223,7 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
     kinds, ffns = cfg.layer_kinds, cfg.ffn_kinds
 
     def block_body(carry, xs):
-        x, lb, drop, imb = carry
+        x, lb, drop, imb, skip = carry
         bp, bc = xs
         if ctx.hidden_constrain is not None:
             # anchor the activation layout every block: stops SPMD sharding
@@ -225,25 +241,28 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                 # weights live).  Its VJP reduce-scatters dW.
                 lp = ctx.block_constrain(lp, j)
 
-            x, nc, l, st = _layer_apply(lp, x, cfg, kinds[j], ffns[j],
-                                        ctx, cache_j, positions, segment_ids)
+            x, nc, l, st, sk = _layer_apply(lp, x, cfg, kinds[j], ffns[j],
+                                            ctx, cache_j, positions,
+                                            segment_ids)
             if new_bc is not None:
                 new_bc[f"pos{j}"] = nc
             lb = lb + l
+            skip = skip + sk
             if st is not None:
                 # mean drop across MoE layers; worst-layer imbalance (the
                 # straggler expert matmul).  NaN (shard_map: unmeasured)
                 # propagates through both — never coerced to 0.0.
                 drop = drop + st[0]
                 imb = jnp.maximum(imb, st[1])
-        return (x, lb, drop, imb), new_bc
+        return (x, lb, drop, imb, skip), new_bc
 
     body = block_body
     if ctx.mode == "train" and cfg.remat and ctx.remat:
         body = jax.checkpoint(block_body, prevent_cse=False)
 
     zero = jnp.zeros((), jnp.float32)
-    carry0 = (x, zero, zero, zero)       # (x, lb, moe drop sum, moe imb max)
+    # (x, lb, moe drop sum, moe imb max, attention (skipped, total))
+    carry0 = (x, zero, zero, zero, jnp.zeros((2,), jnp.float32))
     n_blocks = cfg.n_layers // period
     if cfg.scan_layers and caches is not None and ctx.mode == "decode":
         # decode: keep the stacked caches in the scan CARRY and update the
@@ -262,11 +281,11 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                 caches_all, new_bc)
             return (new_x_lb, caches_all), None
 
-        ((x, lb, drop, imb), new_caches), _ = jax.lax.scan(
+        ((x, lb, drop, imb, skip), new_caches), _ = jax.lax.scan(
             decode_body, (carry0, caches),
             (params["blocks"], jnp.arange(n_blocks)))
     elif cfg.scan_layers:
-        (x, lb, drop, imb), new_caches = jax.lax.scan(
+        (x, lb, drop, imb, skip), new_caches = jax.lax.scan(
             body, carry0, (params["blocks"], caches))
     else:
         new_list = []
@@ -276,7 +295,7 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             bc = jax.tree.map(lambda a: a[b], caches) if caches is not None else None
             carry, nc = body(carry, (bp, bc))
             new_list.append(nc)
-        x, lb, drop, imb = carry
+        x, lb, drop, imb, skip = carry
         new_caches = None
         if caches is not None:
             new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
@@ -290,6 +309,8 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
         # NaN (not 0.0) when the model has no MoE layers at all
         "moe_drop_rate": drop / total_moe if total_moe else nan,
         "moe_imbalance": imb if total_moe else nan,
+        # the Pallas attention's (skipped, total) score elements
+        "attn_skip": skip,
     }
     if ctx.return_hidden or not (cfg.has_lm_head and cfg.vocab_size > 0):
         return x, new_caches, aux

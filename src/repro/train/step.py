@@ -114,29 +114,34 @@ def make_train_step(desc: ModelDesc, opt_cfg: AdamWConfig,
         zero = jnp.zeros((), jnp.float32)
 
         def mb_step(carry, mb):
-            loss_sum, drop_sum, imb_max, grads = carry
+            loss_sum, drop_sum, imb_max, skip, grads = carry
             (l, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
             with jax.named_scope("dflop.grad_accum"):
                 grads = jax.tree.map(
                     lambda a, b: a + b.astype(jnp.float32), grads, g)
             drop_sum = drop_sum + aux["moe_drop_rate"]
             imb_max = jnp.maximum(imb_max, aux["moe_imbalance"])
-            return (loss_sum + l, drop_sum, imb_max, grads), None
+            skip = skip + aux["attn_skip"]
+            return (loss_sum + l, drop_sum, imb_max, skip, grads), None
 
         with jax.named_scope("dflop.grad_accum"):
             acc0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                 params)
-        (loss_sum, drop_sum, imb_max, grads), _ = jax.lax.scan(
-            mb_step, (zero, zero, zero, acc0), batch)
+        (loss_sum, drop_sum, imb_max, skip, grads), _ = jax.lax.scan(
+            mb_step, (zero, zero, zero, jnp.zeros((2,), jnp.float32), acc0),
+            batch)
         with jax.named_scope("dflop.grad_accum"):
             grads = jax.tree.map(lambda g: g / n_mb, grads)
         with jax.named_scope("dflop.optimizer"):
             new_params, new_opt = adamw_update(opt_cfg, params, grads,
                                                opt_state, lr=lr)
-        # NaN-preserving aggregates (no-MoE models report NaN, never 0.0)
+        # NaN-preserving aggregates (no-MoE models report NaN, never 0.0);
+        # attn_skip_share: score elements in the tiles the Pallas attention
+        # skipped over all of its grids' (0 on the XLA paths)
         metrics = {"loss": loss_sum / n_mb,
                    "moe_drop_rate": drop_sum / n_mb,
-                   "moe_imbalance": imb_max}
+                   "moe_imbalance": imb_max,
+                   "attn_skip_share": skip[0] / jnp.maximum(skip[1], 1.0)}
         return new_params, new_opt, metrics
 
     return train_step

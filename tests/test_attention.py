@@ -1,10 +1,17 @@
-"""XLA flash attention (custom VJP) vs naive oracle: fwd + gradients."""
+"""XLA flash attention (custom VJP) vs naive oracle: fwd + gradients;
+which attention a forward runs, and the share of the grid it skips."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.common.types import MLLMConfig, ModalityStub, ModelConfig
+from repro.models import mllm as mllm_lib
+from repro.models import model as model_lib
 from repro.models.layers.attention import attend_naive, flash_attention_xla
+from repro.models.model import FwdCtx, resolve_attn_impl
+from repro.train.optim import AdamWConfig, adamw_init
+from repro.train.step import make_train_step
 
 CASES = [
     # B, S, H, KH, D, causal, window, segs
@@ -116,3 +123,86 @@ def test_cache_write_rejects_malformed_pos():
     out = cache_write(cache, k_new, k_new, jnp.asarray([0, 3]))
     np.testing.assert_array_equal(np.asarray(out["kpos"][0, 0]), 0)
     np.testing.assert_array_equal(np.asarray(out["kpos"][1, 3]), 3)
+
+
+# --------------------------------------------------------------------------- #
+# Which attention runs, and the share of its grid the Pallas kernel skips
+# --------------------------------------------------------------------------- #
+SHARD_CTX = ("mesh", ("data",), ("model",))
+
+
+@pytest.mark.parametrize("backend,mode,sharded,impl,want", [
+    ("tpu", "train", False, None, "pallas"),
+    ("cpu", "train", False, None, "chunked"),
+    ("gpu", "train", False, None, "chunked"),
+    ("tpu", "prefill", False, None, "chunked"),
+    ("tpu", "decode", False, None, "chunked"),
+    ("tpu", "train", True, None, "chunked"),
+    ("cpu", "train", False, "pallas", "pallas"),
+    ("tpu", "train", False, "naive", "naive"),
+    ("tpu", "train", False, "chunked", "chunked"),
+    ("tpu", "prefill", True, "pallas", "pallas"),
+])
+def test_resolve_attn_impl(monkeypatch, backend, mode, sharded, impl, want):
+    """Unset, the Pallas kernel only for a one-device train step on a TPU;
+    a set ``attn_impl`` is always honoured."""
+    monkeypatch.setattr(model_lib.jax, "default_backend", lambda: backend)
+    ctx = FwdCtx(mode=mode, attn_impl=impl,
+                 shard_ctx=SHARD_CTX if sharded else None)
+    assert resolve_attn_impl(ctx) == want
+
+
+def _lm(causal=True):
+    return ModelConfig(name="t", family="dense", n_layers=2, d_model=32,
+                       n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=64,
+                       causal=causal)
+
+
+@pytest.mark.parametrize("impl,want", [("pallas", 0.5), (None, 0.0)])
+def test_train_step_reports_attn_skip_share(impl, want):
+    """2048 positions, the first 1024 segment 1 and the rest 0, causal, in
+    the kernel's 1024-tiles: the tile above the diagonal and the tile
+    across segments are skipped, half the grid in every layer and
+    microbatch.  The XLA path (what an unset ``attn_impl`` runs on the CPU)
+    skips nothing."""
+    cfg = _lm()
+    params = model_lib.init(jax.random.PRNGKey(0), cfg)
+    S = 2048
+    seg = jnp.asarray(np.r_[np.ones(S // 2), np.zeros(S // 2)], jnp.int32)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 1, S), 1, 64)
+    batch = {"tokens": toks, "labels": toks,
+             "segment_ids": jnp.broadcast_to(seg, (2, 1, S))}
+    step = make_train_step(cfg, AdamWConfig(),
+                           ctx=FwdCtx(mode="train", attn_impl=impl),
+                           donate=False)
+    _, _, metrics = step(params, adamw_init(params), batch, 1e-3)
+    assert float(metrics["attn_skip_share"]) == want
+    assert np.isfinite(float(metrics["loss"]))
+
+
+def test_forward_train_sums_encoder_and_llm_skips():
+    """An MLLM row of one real image (1024 patches) and 1024 padded ones:
+    the encoder skips its 2 cross tiles of 4 in each layer, the LLM's
+    single tile is live; the forward's ``attn_skip`` adds both."""
+    enc = ModelConfig(name="e", family="vlm-enc", n_layers=2, d_model=32,
+                      n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=0,
+                      causal=False, use_rope=False, input_embed_dim=16,
+                      has_lm_head=False, activation="gelu")
+    llm = ModelConfig(name="l", family="vlm-llm", n_layers=1, d_model=32,
+                      n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=64)
+    mcfg = MLLMConfig(name="m", encoder=enc, llm=llm,
+                      stub=ModalityStub("vision", 1024, 16),
+                      tokens_per_item_out=64)
+    params = mllm_lib.init(jax.random.PRNGKey(0), mcfg)
+    T, X = 2048, 64
+    batch = {"media_embeds": jnp.ones((1, T, 16)),
+             "media_mask": jnp.asarray(np.r_[np.ones(1024), np.zeros(1024)],
+                                       jnp.int32)[None],
+             "text_tokens": jnp.ones((1, X), jnp.int32),
+             "text_mask": jnp.ones((1, X), jnp.int32)}
+    _, aux = mllm_lib.forward_train(
+        params, mcfg, batch, ctx=FwdCtx(mode="train", attn_impl="pallas"))
+    enc_tile, llm_tile = 1024 * 1024 * 2, (64 + X) ** 2 * 4
+    np.testing.assert_array_equal(
+        np.asarray(aux["attn_skip"]),
+        [2 * 2 * enc_tile, 2 * 4 * enc_tile + 1 * llm_tile])

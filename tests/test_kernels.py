@@ -223,6 +223,101 @@ def test_attention_bwd_fully_masked_query_tile():
                                rtol=5e-4, atol=5e-4)
 
 
+def _layout(name, B, S):
+    """Segment ids of the row layouts the tile skip meets, for 32-wide
+    blocks (0 = padding, blocking.PAD_SEGMENT = a tail no real id
+    matches)."""
+    seg = np.zeros((B, S), np.int32)
+    if name == "real_first":          # encoder rows: real patches, then pad
+        seg[:, :S // 2] = 1
+    elif name == "packed":            # several instances a row
+        # row 0: blocks of one instance each, then of two (cross tiles
+        # empty); row 1: a pure block beside a mixed one (partial tiles)
+        seg[:, :32], seg[:, 32:40], seg[:, 40:] = 1, 2, 3
+        seg[1:, 32:40] = 1
+    elif name == "pad_tail":          # a causal row ending in a reserved tail
+        seg[:, :S * 5 // 8] = 1
+        seg[:, S * 5 // 8:] = blocking.PAD_SEGMENT
+    return jnp.asarray(seg)
+
+
+LAYOUT_CASES = [
+    # (layout, B, H, KH, causal, dtype): 64 positions, D 32, blocks of 32;
+    # each the shape of an ATTN_GRAD_CASES entry, whose compiles it shares
+    ("real_first", 2, 2, 2, False, jnp.float32),
+    ("real_first", 1, 2, 2, True, jnp.bfloat16),
+    ("packed", 2, 2, 2, False, jnp.float32),
+    ("packed", 1, 2, 2, True, jnp.bfloat16),
+    ("pad_tail", 1, 4, 2, True, jnp.float32),
+    ("pad_tail", 1, 2, 2, True, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("layout,B,H,KH,causal,dtype", LAYOUT_CASES)
+def test_tile_skip_matches_float32_oracle(layout, B, H, KH, causal, dtype):
+    """Layouts whose empty tiles the kernels skip: forward and gradients
+    of inputs in ``dtype`` (bfloat16 operands reach the MXU as they are)
+    against the float32 oracle on the same values."""
+    S = 64
+    q, k, v = _mk_qkv(jax.random.PRNGKey(21), B, S, H, KH, 32, dtype)
+    seg = _layout(layout, B, S)
+    skipped, total = ops.attention_skip(q, k, seg, causal=causal,
+                                        block_q=32, block_k=32)
+    assert 0 < float(skipped) < float(total)
+
+    def f_pallas(q, k, v):
+        return ops.packed_flash_attention(q, k, v, segment_ids=seg,
+                                          causal=causal, block_q=32,
+                                          block_k=32)
+
+    def f_ref(q, k, v):
+        return ref.packed_attention_ref(q, k, v, causal=causal, seg_q=seg,
+                                        seg_k=seg)
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    fwd_tol, grad_tol = (2e-2, 5e-2) if dtype == jnp.bfloat16 else (2e-5, 5e-4)
+    np.testing.assert_allclose(np.asarray(f_pallas(q, k, v), np.float32),
+                               np.asarray(f_ref(*f32)), rtol=fwd_tol,
+                               atol=fwd_tol)
+    got = jax.grad(_loss_through(f_pallas), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_loss_through(f_ref), argnums=(0, 1, 2))(*f32)
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.dtype == dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w),
+                                   rtol=grad_tol, atol=grad_tol,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("s,g,block", [
+    (8192, 1, 1024), (1024, 1, 1024),   # InternViT: a 1024-tile fits
+    (1792, 2, 512), (1280, 2, 512),     # InternLM2, GQA 2: 2·1024² does not
+    (96, 1, 96), (300, 8, 256),         # short axis clamped; G 8
+])
+def test_attention_block_follows_the_shape(s, g, block):
+    """The tile edge is the largest MXU multiple whose folded score tile
+    fits the budget, clamped to the axis; the grid pads under one tile."""
+    assert ops.attention_block(s, g) == block
+    assert g * block * block <= ops.ATTN_TILE_ELEMS
+    assert blocking.pick_block(s, block)[1] - s < block
+
+
+def test_attention_skip_counts_the_kernels_grid():
+    """2048 positions in blocks of 512, the first block segment 1 and the
+    rest segment 0, non-causal: the 6 cross tiles of 16 are skipped; with
+    causal the 6 tiles above the diagonal go too, and the tiles left are
+    the 4 on it and the 3 below it in segment 0."""
+    seg = jnp.asarray(np.r_[np.ones(512), np.zeros(1536)].astype(np.int32))
+    seg = jnp.broadcast_to(seg, (2, 2048))
+    q = jnp.zeros((2, 2048, 4, 8))
+    k = jnp.zeros((2, 2048, 2, 8))
+    full = 2 * 2048 * 2048 * 4
+    for causal, live in ((False, 10), (True, 4 + 3)):
+        skipped, total = ops.attention_skip(q, k, seg, causal=causal,
+                                            block_q=512, block_k=512)
+        assert float(total) == full
+        assert float(skipped) / float(total) == (16 - live) / 16
+
+
 def test_gqa_kv_head_mapping():
     """ops.py regression: query head h must read kv head h // G.  With
     uniform attention and per-kv-head constant values, head h's output is

@@ -61,14 +61,19 @@ def _sds(shape, dtype, sharding):
 def _kernel_case(name, sh):
     """(fn, arg shapes) at published widths, bf16 activations."""
     bf16, f32 = jnp.bfloat16, jnp.float32
-    if name == "packed_flash_attention":      # InternLM2-1.8B: H16/KH8/D128
-        B, KH, G, D = 1, 8, 2, 128
-        seg = _sds((B, SEQ), jnp.int32, sh)
+    if name.startswith("packed_flash_attention"):
+        # InternLM2-1.8B: H16/KH8/D128, causal, 512-tiles; InternViT-300M
+        # (`_encoder`): H16/D64, bidirectional, 8192 patches in 1024-tiles
+        enc = name.endswith("_encoder")
+        B, KH, G, D, S, blk = ((2, 16, 1, 64, 2 * SEQ, 1024) if enc
+                               else (1, 8, 2, 128, SEQ, 512))
+        seg = _sds((B, S), jnp.int32, sh)
         return (lambda q, k, v, s: packed_flash_attention_bkgsd(
-                    q, k, v, s, s, causal=True),
-                [_sds((B, KH, G, SEQ, D), bf16, sh),
-                 _sds((B, KH, SEQ, D), bf16, sh),
-                 _sds((B, KH, SEQ, D), bf16, sh), seg], 3)
+                    q, k, v, s, s, causal=not enc, block_q=blk,
+                    block_k=blk),
+                [_sds((B, KH, G, S, D), bf16, sh),
+                 _sds((B, KH, S, D), bf16, sh),
+                 _sds((B, KH, S, D), bf16, sh), seg], 3)
     if name == "mamba_scan":                  # Jamba: d_inner 8192, N 16
         B, di, N = 1, 8192, 16
         return (mamba_scan_bsd,
@@ -91,7 +96,8 @@ def _summed(fn):
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 @pytest.mark.parametrize("kernel", ["packed_flash_attention", "mamba_scan",
-                                    "rwkv6_scan"])
+                                    "rwkv6_scan",
+                                    "packed_flash_attention_encoder"])
 def test_kernel_compiles_for_v5e(one_chip, kernel, direction):
     fn, args, n_diff = _kernel_case(kernel, one_chip)
     if direction == "backward":
@@ -134,3 +140,39 @@ def test_internvl2_train_step_fits_one_v5e(one_chip):
     assert 0 < live < HBM_BYTES, live
     # the donated params and moments are updated in place
     assert ma.alias_size_in_bytes > 0.9 * ma.output_size_in_bytes
+
+
+def test_internvl2_train_step_on_the_kernel_fits_one_v5e(one_chip,
+                                                         monkeypatch):
+    """What an unset ``attn_impl`` runs on a TPU: the ``ivl2`` train step
+    (depth 3, 2 microbatches of 2 rows of 8192 patches and 1536 text
+    tokens) with the Pallas attention in encoder and LLM, Mosaic-compiled.
+    The backend is reported as a TPU so the program takes its chip path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    full = get_config("internvl2-2b").desc
+    desc = dataclasses.replace(
+        full, encoder=dataclasses.replace(full.encoder, n_layers=3),
+        llm=dataclasses.replace(full.llm, n_layers=3))
+
+    def place(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: mllm_lib.init(jax.random.PRNGKey(0), desc)))
+    opt = place(jax.eval_shape(adamw_init, params))
+    lead, T, E = (2, 2), 8 * desc.stub.n_tokens, desc.stub.embed_dim
+    batch = {"media_embeds": _sds(lead + (T, E), jnp.float32, one_chip),
+             "media_mask": _sds(lead + (T,), jnp.int32, one_chip)}
+    for key in ("text_tokens", "text_mask", "labels"):
+        batch[key] = _sds(lead + (1536,), jnp.int32, one_chip)
+    step = jax.jit(make_train_step(desc, AdamWConfig(),
+                                   ctx=FwdCtx(mode="train")),
+                   donate_argnums=(0, 1))
+    compiled = step.lower(params, opt, batch,
+                          _sds((), jnp.float32, one_chip)).compile()
+    # forward, recompute, dq and dk/dv of the encoder's and LLM's attention
+    assert compiled.as_text().count("tpu_custom_call") >= 8
+    ma = compiled.memory_analysis()
+    live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < live < HBM_BYTES, live
