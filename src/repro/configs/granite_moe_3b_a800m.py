@@ -28,5 +28,5 @@ SPEC = register(ArchSpec(
     citation="hf:ibm-granite/granite-3.0-1b-a400m-base",
     notes="Fine-grained MoE: 40 small experts, top-8 routing, every layer MoE. "
           "40 experts do not divide the 16-wide model axis, so expert "
-          "parallelism falls back to FFN-dim sharding (see DESIGN.md).",
+          "parallelism falls back to FFN-dim sharding.",
 ))

@@ -31,7 +31,7 @@ SPEC = register(ArchSpec(
     desc=CFG,
     citation="arXiv:2106.07447 (HuBERT)",
     notes="Encoder-only: no decode step — decode_32k and long_500k are "
-          "documented skips (DESIGN.md §4). train_4k = masked prediction "
+          "skipped (ArchSpec.shape_support). train_4k = masked prediction "
           "over 4k frames; prefill_32k = pure encoding forward. Also serves "
           "as the audio-encoder stage of the Qwen2-Audio-style MLLM (Fig. 9).",
 ))

@@ -29,5 +29,5 @@ SPEC = register(ArchSpec(
     citation="arXiv:2401.04088 (Mixtral of Experts)",
     notes="Native sliding-window attention -> long_500k runs with a "
           "ring-buffer KV cache of W=4096. 8 experts < 16-wide model axis: "
-          "expert weights shard on the FFN dim (see DESIGN.md).",
+          "expert weights shard on the FFN dim.",
 ))

@@ -1,4 +1,5 @@
-"""Shared grid-blocking policy for the Pallas kernels.
+"""Shared grid-blocking policy for the Pallas kernels and XLA's chunked
+attention.
 
 Every kernel tiles a sequence (or channel) axis into ``block``-sized grid
 steps.  The old per-kernel ``_pick`` helper chose the largest *divisor* of
@@ -10,13 +11,13 @@ axis up to the next block multiple and lets masking neutralize the tail:
   * attention — padded positions carry segment id ``PAD_SEGMENT`` (−1),
     which can never equal a real segment id (callers use ids ≥ 0), so the
     existing segment mask hides the tail for free; padded query rows are
-    zeroed by the ``l > 0`` finalize guard and sliced off.
+    sliced off.
   * scans — padded steps are identities (mamba: dt = 0 ⇒ decay = 1, no
     input; rwkv: w = 1, k = v = 0 ⇒ state passes through), so the final
     state and all real-position outputs are untouched.
 
 Gradients need no special handling: padding/slicing happen *outside* the
-kernels' ``custom_vjp`` boundary with plain ``jnp.pad``/slice, whose
+``custom_vjp`` boundary with plain ``jnp.pad``/slice, whose
 transposes drop the tail cotangents automatically.
 
 >>> pick_block(128, 64)      # divisible: exact tiling, no padding
@@ -83,3 +84,10 @@ def pad_axis(x, padded: int, axis: int, value=0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, padded - n)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def pad_segments(seg, block: int):
+    """``(block, padded length, ids padded with PAD_SEGMENT)`` of an
+    attention call's ``(B, S)`` segment ids tiled by ``block``."""
+    b, padded = pick_block(seg.shape[1], block)
+    return b, padded, pad_axis(seg, padded, axis=1, value=PAD_SEGMENT)

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocking import PAD_SEGMENT, pad_axis, pick_block
+from repro.kernels.blocking import pad_axis, pad_segments
 
 NEG_INF = -1e30
 
@@ -466,12 +466,6 @@ def _flash_bwd_rule(causal, window, bq, bk, interpret, res, dout):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _padded(seg, s: int, block: int):
-    """(block, padded length, ids padded with PAD_SEGMENT)."""
-    b, s_p = pick_block(s, block)
-    return b, s_p, pad_axis(seg, s_p, axis=1, value=PAD_SEGMENT)
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def packed_flash_attention_bkgsd(q, k, v, seg_q, seg_k, *, causal: bool = True,
@@ -479,9 +473,9 @@ def packed_flash_attention_bkgsd(q, k, v, seg_q, seg_k, *, causal: bool = True,
                                  block_k: int = 512, interpret: bool = False):
     """q: (B, KH, G, Sq, D); k, v: (B, KH, Sk, D); seg_*: (B, S) int32.
     Returns (B, KH, G, Sq, D).  Differentiable in (q, k, v)."""
-    Sq, Sk = q.shape[3], k.shape[2]
-    bq, Sq_p, seg_q = _padded(seg_q, Sq, block_q)
-    bk, Sk_p, seg_k = _padded(seg_k, Sk, block_k)
+    Sq = q.shape[3]
+    bq, Sq_p, seg_q = pad_segments(seg_q, block_q)
+    bk, Sk_p, seg_k = pad_segments(seg_k, block_k)
     q = pad_axis(q, Sq_p, axis=3)
     k = pad_axis(k, Sk_p, axis=2)
     v = pad_axis(v, Sk_p, axis=2)
@@ -494,8 +488,8 @@ def skip_counts(seg_q, seg_k, *, causal: bool = True, window: int = 0,
     """(skipped, total) score elements per head of the call's grid, float32:
     the elements of the tiles ``packed_flash_attention_bkgsd`` skips, from
     the same padding and block bounds."""
-    bq, _, seg_q = _padded(seg_q, seg_q.shape[1], block_q)
-    bk, _, seg_k = _padded(seg_k, seg_k.shape[1], block_k)
+    bq, _, seg_q = pad_segments(seg_q, block_q)
+    bk, _, seg_k = pad_segments(seg_k, block_k)
     live = _live_tiles(seg_q, seg_k, causal=causal, window=window, bq=bq,
                       bk=bk)
     area = float(bq * bk)

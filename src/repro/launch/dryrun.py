@@ -48,8 +48,8 @@ from repro.train.step import make_train_step
 # per-arch microbatch counts for train_4k (memory-driven)
 N_MB = {"default": 8, "jamba-v0.1-52b": 16, "mixtral-8x7b": 16,
         "starcoder2-15b": 16}
-# per-arch MoE dispatch chunk (tokens)
-MOE_CHUNK = {"default": 8192}
+# MoE dispatch chunk (tokens)
+MOE_CHUNK = 8192
 
 MEM_CAP_BYTES = 16e9        # v5e HBM
 
@@ -62,7 +62,7 @@ def make_assignment(mesh, spec: ArchSpec, *, heterogeneous: bool = True,
     """DFLOP plan on the fixed mesh: LLM uses the model axis for tensor
     sharding; the encoder (small, batch-rich) runs tp=1 with the model axis
     joined to its batch sharding — the SPMD realization of independent
-    per-module 3D parallelism (DESIGN.md §2)."""
+    per-module 3D parallelism."""
     b, m = batch_axes(mesh), model_axes(mesh)
     zero = b          # ZeRO over all batch axes (pod + data on multi-pod)
     llm = AxisAssignment(batch=b, tensor=m, zero=zero, fsdp=fsdp)
@@ -78,7 +78,7 @@ def make_assignment(mesh, spec: ArchSpec, *, heterogeneous: bool = True,
 def moe_constrain_fn(mesh, cfg: ModelConfig, assignment: AxisAssignment):
     """Sharding constraint for the (E, C, d) MoE dispatch buffers: expert
     parallelism when E divides the tensor axes, else shard capacity over the
-    batch axes (DESIGN.md §4 notes on granite/mixtral)."""
+    batch axes (granite's 40 and mixtral's 8 experts on a 16-wide axis)."""
     if cfg.n_experts == 0:
         return None
     t = assignment.tensor
@@ -270,11 +270,10 @@ def build_train(spec: ArchSpec, shape: ShapeSpec, mesh):
     if isinstance(desc, MLLMConfig):
         communicator = make_communicator(mesh, assignment.for_module("encoder"),
                                          assignment.llm)
-    ctx = FwdCtx(mode="train", attn_impl="chunked", attn_block=1024,
+    ctx = FwdCtx(mode="train", attn_impl="chunked",
                  ssm_impl="chunked", moe_impl="ep",
                  capacity_factor=1.25,
-                 moe_chunk_tokens=MOE_CHUNK.get(spec.arch_id,
-                                                MOE_CHUNK["default"]),
+                 moe_chunk_tokens=MOE_CHUNK,
                  moe_constrain=moe_constrain_fn(mesh, llm_cfg, assignment.llm),
                  hidden_constrain=hidden_constrain_fn(mesh, assignment.llm),
                  logits_constrain=logits_constrain_fn(mesh, llm_cfg,
@@ -336,9 +335,9 @@ def build_prefill(spec: ArchSpec, shape: ShapeSpec, mesh):
                   if isinstance(desc, MLLMConfig)
                   else params_shapes["blocks"])
     ctx = FwdCtx(mode="prefill", remat=False, attn_impl="chunked",
-                 attn_block=1024, ssm_impl="chunked", moe_impl="ep",
+                 ssm_impl="chunked", moe_impl="ep",
                  capacity_factor=1.25,
-                 moe_chunk_tokens=8192,
+                 moe_chunk_tokens=MOE_CHUNK,
                  moe_constrain=moe_constrain_fn(mesh, llm_cfg, assignment.llm),
                  hidden_constrain=hidden_constrain_fn(mesh, assignment.llm),
                  logits_constrain=logits_constrain_fn(mesh, llm_cfg,

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.types import ModelConfig
+from repro.kernels.blocking import pad_axis, pad_segments
 from repro.models.layers.rope import apply_rope
 
 NEG_INF = -1e30
@@ -92,37 +93,42 @@ def attend_naive(q, k, v, *, causal=True, window=0, seg_q=None, seg_k=None,
 # --------------------------------------------------------------------------- #
 # Chunked XLA flash attention (custom VJP: FlashAttention-2-style backward)
 # --------------------------------------------------------------------------- #
-def _pick_block(s: int, target: int) -> int:
-    b = min(s, target)
-    while s % b:
-        b -= 1
-    return b
+# Tile edge of the chunked path's blocks left unset.
+BLOCK = 512
 
 
 def flash_attention_xla(q, k, v, *, causal=True, window=0, seg_q=None,
                         seg_k=None, q_offset=0, scale=None,
-                        block_q=512, block_k=512):
+                        block_q=None, block_k=None):
     """Flash attention built from nested lax.scans, with a custom VJP.
 
     Without the custom VJP, differentiating the scan forward saves the
     per-block probabilities as residuals — the full S^2 attention matrix
     (8+ GB at 32k) — defeating the chunked formulation.  The backward pass
     recomputes p block-by-block from the saved log-sum-exp instead
-    (FlashAttention-2), so train-time memory stays O(S * block)."""
+    (FlashAttention-2), so train-time memory stays O(S * block).
+
+    Tiled as the Pallas kernel is (``repro.kernels.blocking``): each axis
+    is padded to whole tiles of ``block_q`` / ``block_k`` (``BLOCK`` when
+    unset), the padded ids are ``PAD_SEGMENT``, and the output is sliced
+    back to ``Sq``, all outside the custom VJP."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if seg_q is None:
         seg_q = jnp.zeros(q.shape[:2], jnp.int32)
     if seg_k is None:
         seg_k = jnp.zeros(k.shape[:2], jnp.int32)
-    return _flash(q, k, v, seg_q, seg_k, causal, window, q_offset, scale,
-                  block_q, block_k)
+    bq, Sq_p, seg_q = pad_segments(seg_q, block_q or BLOCK)
+    bk, Sk_p, seg_k = pad_segments(seg_k, block_k or BLOCK)
+    out = _flash(pad_axis(q, Sq_p, axis=1), pad_axis(k, Sk_p, axis=1),
+                 pad_axis(v, Sk_p, axis=1), seg_q, seg_k, causal, window,
+                 q_offset, scale, bq, bk)
+    return out[:, :q.shape[1]]
 
 
-def _blockify(q, k, v, seg_q, seg_k, block_q, block_k):
+def _blockify(q, k, v, seg_q, seg_k, bq, bk):
     B, Sq, H, D = q.shape
     Sk, Kh = k.shape[1], k.shape[2]
     G = H // Kh
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
     nq, nk = Sq // bq, Sk // bk
     qb = q.reshape(B, nq, bq, Kh, G, D).astype(jnp.float32)
     kb = k.reshape(B, nk, bk, Kh, D).astype(jnp.float32)
@@ -358,8 +364,7 @@ def cache_write(cache, k_new, v_new, pos):
 # Layer apply
 # --------------------------------------------------------------------------- #
 def apply(params, x, cfg: ModelConfig, *, positions=None, segment_ids=None,
-          cache=None, decode_pos=None, impl: str = "chunked",
-          block: int = 512):
+          cache=None, decode_pos=None, impl: str = "chunked"):
     """Self-attention layer.
 
     Train/prefill: cache is None, x is (B,S,d).
@@ -405,8 +410,7 @@ def apply(params, x, cfg: ModelConfig, *, positions=None, segment_ids=None,
             else:
                 out = flash_attention_xla(q, k, v, causal=cfg.causal,
                                           window=window, seg_q=segment_ids,
-                                          seg_k=segment_ids,
-                                          block_q=block, block_k=block)
+                                          seg_k=segment_ids)
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return y, cache, skip

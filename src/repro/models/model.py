@@ -33,7 +33,6 @@ class FwdCtx:
     mode: str = "train"              # train | prefill | decode
     attn_impl: Optional[str] = None  # naive | chunked | pallas; None:
                                      # resolve_attn_impl picks
-    attn_block: int = 512            # chunked path's (block_q, block_k) tile
     ssm_impl: str = "xla"            # xla | pallas
     moe_impl: str = "capacity"       # dense | capacity
     capacity_factor: float = 2.0
@@ -103,7 +102,7 @@ def _layer_apply(lp, x, cfg: ModelConfig, kind: LayerKind, ffn_kind: FFNKind,
         y, new_attn_cache, skip = attention.apply(
             lp["attn"], h, cfg, positions=positions, segment_ids=segment_ids,
             cache=attn_cache, decode_pos=ctx.decode_pos,
-            impl=resolve_attn_impl(ctx), block=ctx.attn_block)
+            impl=resolve_attn_impl(ctx))
         new_cache = {"attn": new_attn_cache} if cache else None
     elif kind == LayerKind.MAMBA:
         m_cache = cache.get("mamba") if cache else None
@@ -136,7 +135,7 @@ def _layer_apply(lp, x, cfg: ModelConfig, kind: LayerKind, ffn_kind: FFNKind,
                                capacity_factor=ctx.capacity_factor,
                                constrain=ctx.moe_constrain,
                                chunk_tokens=ctx.moe_chunk_tokens,
-                               shard_ctx=ctx.shard_ctx, with_stats=True)
+                               shard_ctx=ctx.shard_ctx)
         moe_stats = (jax.lax.stop_gradient(st["drop_rate"]),
                      jax.lax.stop_gradient(st["imbalance"]))
     else:

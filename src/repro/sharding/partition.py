@@ -184,7 +184,7 @@ def param_specs(params: Any, assignment: ModuleAssignment, mesh: Mesh) -> Any:
             spec = P()
         # MoE expert weights: expert-dim sharding when E divides the tensor
         # axes, else shard the FFN dim (granite 40e / mixtral 8e vs a
-        # 16-wide model axis — DESIGN.md §4).
+        # 16-wide model axis).
         m = re.search(r"/moe/(w_gate|w_up|w_down)$", path)
         if m and a.tensor:
             tsize = _axes_size(mesh, tuple(a.tensor))

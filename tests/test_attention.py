@@ -8,7 +8,8 @@ import pytest
 from repro.common.types import MLLMConfig, ModalityStub, ModelConfig
 from repro.models import mllm as mllm_lib
 from repro.models import model as model_lib
-from repro.models.layers.attention import attend_naive, flash_attention_xla
+from repro.models.layers.attention import (BLOCK, attend_naive,
+                                           flash_attention_xla)
 from repro.models.model import FwdCtx, resolve_attn_impl
 from repro.train.optim import AdamWConfig, adamw_init
 from repro.train.step import make_train_step
@@ -19,6 +20,8 @@ CASES = [
     (1, 96, 4, 1, 32, True, 24, True),
     (2, 64, 4, 4, 32, False, 0, False),
     (1, 128, 8, 8, 64, True, 0, False),
+    (1, 97, 4, 2, 32, True, 24, True),      # prime: 4 tiles, padded
+    (2, 100, 4, 4, 32, False, 0, False),    # only the padding masks the tail
 ]
 
 
@@ -85,6 +88,32 @@ def test_flash_no_quadratic_residuals():
             assert not (leaf.ndim >= 2 and leaf.shape[-1] == S
                         and leaf.shape[-2] == S), \
                 f"quadratic residual {leaf.shape}"
+
+
+def _scan_lengths(jaxpr):
+    """Lengths of every ``lax.scan`` in ``jaxpr`` and the jaxprs it calls."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(eqn.params["length"])
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _scan_lengths(sub)
+    return out
+
+
+def test_flash_default_blocks_pad_to_whole_tiles():
+    """At a length that is not a multiple of ``BLOCK`` the default tiles
+    pad to ceil(S / BLOCK) grid steps, forward and backward, where a
+    divisor search would fall to tiles of 1 at a prime length."""
+    S = 1031
+    q, k, v, _ = _inputs(1, S, 2, 1, 8, False)
+    f = lambda q, k, v: jnp.sum(flash_attention_xla(q, k, v))
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, v)
+    lengths = _scan_lengths(jaxpr.jaxpr)
+    assert lengths and set(lengths) == {-(-S // BLOCK)}, lengths
 
 
 # --------------------------------------------------------------------------- #

@@ -434,8 +434,7 @@ def test_model_grad_through_pallas_impls():
         out, _, _ = model_lib.forward(params, cfg, tokens=toks, ctx=ctx)
         return jnp.mean(jnp.sin(out.astype(jnp.float32)))
 
-    ctx_p = FwdCtx(mode="train", attn_impl="pallas", ssm_impl="pallas",
-                   attn_block=32)
+    ctx_p = FwdCtx(mode="train", attn_impl="pallas", ssm_impl="pallas")
     ctx_r = FwdCtx(mode="train", attn_impl="naive", ssm_impl="xla")
     g_p = jax.grad(loss)(params, ctx_p)
     g_r = jax.grad(loss)(params, ctx_r)

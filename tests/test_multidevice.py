@@ -237,9 +237,26 @@ def test_dryrun_smoke_small_mesh():
     assert "compiled OK" in out
 
 
+# Appended to the MoE scripts below: on a (1, 4) mesh every shard routes
+# the whole token set, so the shard_map MoE equals the one-device capacity
+# dispatch at a capacity factor that drops assignments.
+TIGHT_CAPACITY = """
+        mesh14 = make_mesh((1, 4), ("data", "model"), devices=jax.devices()[:4])
+        y_cap, _, st = moe.apply_capacity(p, x, cfg, capacity_factor=0.5)
+        assert float(st["drop_rate"]) > 0
+        with mesh14:
+            y_sm, _, _ = jax.jit(lambda p, x: moe.apply_ep_shard_map(
+                p, x, cfg, (mesh14, ("data",), ("model",)),
+                capacity_factor=0.5))(p, x)
+        np.testing.assert_allclose(np.asarray(y_cap), np.asarray(y_sm),
+                                   rtol=2e-3, atol=2e-3)
+        print("OK")
+"""
+
+
 def test_ep_shard_map_moe_matches_dense():
-    """Expert-parallel shard_map MoE (§Perf iteration 7) vs the dense
-    oracle (high capacity factor -> no drops)."""
+    """Expert-parallel shard_map MoE vs the dense oracle (high capacity
+    factor -> no drops), and vs the capacity dispatch at a tight one."""
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.common.types import ModelConfig
@@ -252,20 +269,20 @@ def test_ep_shard_map_moe_matches_dense():
                           dtype="float32")
         p = moe.init(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64))
-        y_ref, _ = moe.apply_dense(p, x, cfg)
+        y_ref, _, _ = moe.apply_dense(p, x, cfg)
         with mesh:
-            y_ep, lb = jax.jit(lambda p, x: moe.apply_ep_shard_map(
+            y_ep, lb, st = jax.jit(lambda p, x: moe.apply_ep_shard_map(
                 p, x, cfg, (mesh, ("data",), ("model",)),
                 capacity_factor=8.0))(p, x)
         np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_ep),
                                    rtol=2e-3, atol=2e-3)
+        assert np.isnan(float(st["drop_rate"]))     # not measured: NaN
         g = jax.grad(lambda p: jnp.sum(moe.apply_ep_shard_map(
             p, x, cfg, (mesh, ("data",), ("model",)),
             capacity_factor=8.0)[0]**2))(p)
         assert all(np.isfinite(np.asarray(l)).all()
                    for l in jax.tree_util.tree_leaves(g))
-        print("OK")
-        """)
+        """ + TIGHT_CAPACITY)
     assert "OK" in out
 
 
@@ -303,7 +320,8 @@ def test_sharded_mamba_scan_matches_plain():
 
 def test_tp_expert_shard_map_moe_non_divisible():
     """E ∤ model-axis fallback: TP-sharded experts with local dispatch
-    (mixtral 8e / granite 40e on a 16-wide axis)."""
+    (mixtral 8e / granite 40e on a 16-wide axis), vs the dense oracle and
+    vs the capacity dispatch at a tight capacity factor."""
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.common.types import ModelConfig
@@ -316,13 +334,12 @@ def test_tp_expert_shard_map_moe_non_divisible():
                           dtype="float32")       # 6 experts over 4-wide axis
         p = moe.init(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64))
-        y_ref, _ = moe.apply_dense(p, x, cfg)
+        y_ref, _, _ = moe.apply_dense(p, x, cfg)
         with mesh:
-            y_tp, _ = jax.jit(lambda p, x: moe.apply_ep_shard_map(
+            y_tp, _, _ = jax.jit(lambda p, x: moe.apply_ep_shard_map(
                 p, x, cfg, (mesh, ("data",), ("model",)),
                 capacity_factor=8.0))(p, x)
         np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_tp),
                                    rtol=2e-3, atol=2e-3)
-        print("OK")
-        """)
+        """ + TIGHT_CAPACITY)
     assert "OK" in out

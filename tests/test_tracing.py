@@ -1,6 +1,6 @@
 """Per-module device time: the program's ``dflop.`` named scopes in the
 compiled train step, its host spans in the profiler's trace, and
-``bench/trace_scopes.py``, which attributes device ops to them."""
+``bench/trace_reduce.py``, which attributes device ops to them."""
 from __future__ import annotations
 
 import glob
@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import trace_scopes as ts
+from bench import trace_reduce as ts
 from repro.common.trace import TraceRecorder
 from repro.data.items import DataItem
 from repro.data.synthetic import MixedDataset
@@ -125,7 +125,7 @@ def test_compiled_train_step_carries_every_scope():
     """The tiny cell's step, compiled as the benchmark compiles it: all
     seven scopes, and encoder, LLM, head and attention in a forward and
     in a backward op."""
-    from bench.harness.train_1chip import to_desc
+    from bench.harness.train import to_desc
     from bench.tests import tiny
     from repro.models import mllm as mllm_lib
     from repro.models.model import FwdCtx
